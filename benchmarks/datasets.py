@@ -37,7 +37,18 @@ SPECS = {
 }
 
 
+# rows built at a time: host memory stays at the output plus
+# O(_CHUNK · active_dims · d) at any n
+_CHUNK = 65536
+
+
 def make_dataset(name: str, seed: int = 0, n: int | None = None) -> np.ndarray:
+    """``n`` points of the named twin (default: the spec's reduced n).
+
+    Rows are built ``_CHUNK`` at a time; the random streams are drawn in
+    the same order for every chunk size, so the points do not depend on
+    it.
+    """
     spec = SPECS[name]
     n = n or spec.n
     rng = np.random.default_rng(seed)
@@ -49,10 +60,17 @@ def make_dataset(name: str, seed: int = 0, n: int | None = None) -> np.ndarray:
     basis /= np.linalg.norm(basis, axis=-1, keepdims=True)
     asg = rng.integers(0, spec.clusters, n)
     coeff = rng.normal(size=(n, spec.active_dims)).astype(np.float32)
-    pts = centers[asg] + np.einsum("na,nad->nd", coeff, basis[asg])
+    pts = np.empty((n, spec.d), np.float32)
+    for s in range(0, n, _CHUNK):
+        a = asg[s:s + _CHUNK]
+        pts[s:s + _CHUNK] = centers[a] + np.einsum(
+            "na,nad->nd", coeff[s:s + _CHUNK], basis[a])
     # a pinch of full-rank noise so distances are non-degenerate
-    pts += rng.normal(size=(n, spec.d)).astype(np.float32) * 0.05
-    return pts.astype(np.float32)
+    for s in range(0, n, _CHUNK):
+        rows = min(_CHUNK, n - s)
+        pts[s:s + rows] += (rng.normal(size=(rows, spec.d)).astype(np.float32)
+                            * 0.05)
+    return pts
 
 
 def make_queries(data: np.ndarray, n_queries: int, seed: int = 1) -> np.ndarray:

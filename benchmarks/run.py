@@ -105,6 +105,9 @@ def main() -> None:
                     help="directory for BENCH_<name>.json (default: cwd)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.compile_cache import enable_compile_cache
+
+    print(f"# compile cache: {enable_compile_cache()}", flush=True)
 
     print("name,us_per_call,derived")
     failed = []

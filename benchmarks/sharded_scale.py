@@ -28,6 +28,7 @@ the modeled exchange bytes must stay below the verify bytes at every P.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from .common import csv_row, publish_summary, timer_samples
@@ -85,7 +86,8 @@ def run(quick: bool = True):
     comm, lat = [], {}
     for P in SHARD_COUNTS:
         index = build_index(data, IndexConfig(
-            backend="sharded-flat", seed=0, options={"shards": P}))
+            backend="sharded-flat", seed=0,
+            options={"shards": P, "emulate": P > jax.device_count()}))
         res, samples = timer_samples(
             lambda idx=index: idx.search(queries, K), repeats=repeats)
         # exactness is the contract — a drifting benchmark fails loudly

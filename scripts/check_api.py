@@ -42,6 +42,7 @@ from __future__ import annotations
 import sys
 import time
 
+import jax
 import numpy as np
 
 
@@ -356,7 +357,8 @@ def check_sharded(data, queries, rng) -> None:
     for P in shard_counts:
         idx = build_index(data, IndexConfig(
             backend="sharded-flat", seed=0,
-            options={"shards": P, "force": "ref"}))
+            options={"shards": P, "force": "ref",
+                     "emulate": P > jax.device_count()}))
         rs = idx.search(queries, k)
         np.testing.assert_array_equal(
             rf.indices, rs.indices,
@@ -392,7 +394,8 @@ def check_sharded(data, queries, rng) -> None:
     ref = _recall(fpq.search(queries, k), exact)
     spq = build_index(data, IndexConfig(
         backend="sharded-flat-pq", seed=0,
-        options={"shards": max(shard_counts), "force": "ref"}))
+        options={"shards": max(shard_counts), "force": "ref",
+                 "emulate": max(shard_counts) > jax.device_count()}))
     rq = spq.search(queries, k)
     rec = _recall(rq, exact)
     assert rec >= 0.95 * ref, (

@@ -1,84 +1,49 @@
-"""Version-compatibility shims over the jax API surface.
-
-The repo targets current jax, where ``jax.shard_map`` / ``check_vma`` /
-``jax.sharding.AxisType`` are public; older installs (≤ 0.4.x) spell
-these ``jax.experimental.shard_map.shard_map`` / ``check_rep`` and have
-no axis types.  Every sharded code path goes through these helpers so
-the rest of the tree can be written against one spelling.
-"""
+"""Mesh and shard_map helpers in the one spelling every sharded path uses."""
 from __future__ import annotations
 
+import math
+
 import jax
+import numpy as np
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` with replication checking off, on any jax.
+    """``jax.shard_map`` with replication checking off.
 
     Outputs of every caller in this repo are value-replicated after an
     all-gather/psum, which the static replication checker cannot prove —
-    hence ``check_vma=False`` (new) / ``check_rep=False`` (old).
-    ``axis_names`` restricts manual axes (new spelling); on old jax it
-    maps to the complementary ``auto`` set.
+    hence ``check_vma=False``.  ``axis_names`` restricts the manual axes.
     """
-    impl = getattr(jax, "shard_map", None)
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl
-
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-        if axis_names is not None:
-            kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return impl(f, **kwargs)
-    for check in ({"check_vma": False}, {"check_rep": False}):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **check)
-        if axis_names is not None:
-            kwargs["axis_names"] = frozenset(axis_names)
-        try:
-            return impl(f, **kwargs)
-        except TypeError:
-            continue
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    kwargs = {} if axis_names is None else {"axis_names": frozenset(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False, **kwargs)
 
 
 def make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axis_names, axis_types=(axis_type.Auto,) * len(shape)
-            )
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` over every visible device, with Auto axes."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_submesh(shape, axis_names, devices=None):
     """A mesh over the FIRST prod(shape) devices.
 
-    ``jax.make_mesh`` (and its older spellings) insists on consuming
-    every visible device, which makes "run the P=2 layout on the
-    8-device CI host" impossible through it — the shim gap the sharded
-    parity suite surfaced.  Build the Mesh directly over a device
-    prefix instead; falls back to :func:`make_mesh` when the shapes
-    happen to cover everything (keeping Auto axis types where they
-    exist).
+    ``jax.make_mesh`` insists on consuming every visible device, which
+    makes "run the P=2 layout on the 8-device CI host" impossible
+    through it.  Build the Mesh directly over a device prefix instead;
+    when the shape covers every device, :func:`make_mesh` picks the
+    device order.
     """
-    import math
-
-    import numpy as np
-
     devices = list(jax.devices()) if devices is None else list(devices)
     need = math.prod(shape)
     if need > len(devices):
         raise ValueError(
             f"mesh shape {tuple(shape)} needs {need} devices, "
             f"only {len(devices)} visible")
-    if need == len(devices):
-        try:
-            return make_mesh(tuple(shape), tuple(axis_names))
-        except Exception:
-            pass
+    if need == len(devices) == len(jax.devices()):
+        return make_mesh(tuple(shape), tuple(axis_names))
     grid = np.array(devices[:need]).reshape(tuple(shape))
-    return jax.sharding.Mesh(grid, tuple(axis_names))
+    return jax.sharding.Mesh(
+        grid, tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape))

@@ -42,7 +42,8 @@ from repro.obs import trace as otrace
 from .estimator import solve_parameters
 from .hashing import ProjectionFamily
 
-__all__ = ["CpFusedResult", "cp_fused_search", "cp_threshold2"]
+__all__ = ["CpFusedResult", "cp_fused_search", "cp_join_budget",
+           "cp_threshold2"]
 
 
 @dataclasses.dataclass
@@ -66,6 +67,17 @@ def cp_threshold2(c: float, m: int, gamma: float,
     """
     t = solve_parameters(c, m=m, alpha1=alpha1).t
     return float(gamma * t) ** 2
+
+
+def cp_join_budget(k: int, n_pairs: int) -> int:
+    """Pairs the join keeps for a k-pair answer: 2k, within the
+    kernel's k ≤ 128 regime.  The join ranks by norm-trick float32
+    distances, whose cancellation error can reorder pairs near the k-th
+    (on the Deep twin at n = 16384, k = 10, a k-pair pool lost a true
+    top-k pair in 3 of 24 seeds; the 2k pool in none).  The exact
+    re-rank of the wider pool makes such a loss rarer; it cannot rule
+    it out."""
+    return min(max(k, min(2 * k, 128)), n_pairs)
 
 
 def cp_fused_search(
@@ -129,8 +141,9 @@ def cp_fused_search(
             xs, ks = data[order], key[order]
         with otrace.span("cp.join"):
             thresh2 = cp_threshold2(c, m, gamma)
-            d2, pi, pj, stats = kops.pair_join(xs, ks, kk, thresh2=thresh2,
-                                               force=force, block_n=block_n)
+            d2, pi, pj, stats = kops.pair_join(
+                xs, ks, cp_join_budget(kk, n * (n - 1) // 2),
+                thresh2=thresh2, force=force, block_n=block_n)
             d2 = np.asarray(d2)
             pi = np.asarray(pi)
             pj = np.asarray(pj)
@@ -145,15 +158,15 @@ def cp_fused_search(
                              axis=1).astype(np.int32)
             # the join ranks pairs by norm-trick distances (MXU form),
             # which cancel catastrophically exactly where CP answers
-            # live — between near-duplicates.  Recompute the k winners
-            # in the stable subtract-then-norm form (k rows,
-            # negligible) and re-sort, so reported distances are
-            # exactly what a direct verification gives.
+            # live — between near-duplicates.  Recompute the pool in
+            # the stable subtract-then-norm form (2k rows, negligible)
+            # and re-sort, so the k reported pairs and distances are
+            # what a direct verification gives.
             diff = (data[pairs[:, 0].astype(np.int64)]
                     - data[pairs[:, 1].astype(np.int64)])
             dists = np.sqrt(np.sum(diff.astype(np.float32) ** 2, axis=1)
                             ).astype(np.float32)
-            resort = np.argsort(dists, kind="stable")
+            resort = np.argsort(dists, kind="stable")[:kk]
     return CpFusedResult(pairs=pairs[resort], distances=dists[resort],
                          pairs_verified=int(stats[0]),
                          tiles_pruned=int(stats[1]))

@@ -51,7 +51,8 @@ class ProjectionFamily:
 
     def project(self, x: jax.Array) -> jax.Array:
         """Project points (..., d) into the m-dim hash space: x @ a."""
-        return jnp.asarray(x, jnp.float32) @ self.a
+        return jnp.dot(jnp.asarray(x, jnp.float32), self.a,
+                       precision=jax.lax.Precision.HIGHEST)
 
     def __call__(self, x: jax.Array) -> jax.Array:  # alias
         return self.project(x)
@@ -82,7 +83,8 @@ class BucketFamily:
 
     def raw(self, x: jax.Array) -> jax.Array:
         """Un-floored hash value (a·x + b)/w, useful for probing sequences."""
-        return (jnp.asarray(x, jnp.float32) @ self.a + self.b) / self.w
+        return (jnp.dot(jnp.asarray(x, jnp.float32), self.a,
+                        precision=jax.lax.Precision.HIGHEST) + self.b) / self.w
 
     def hash(self, x: jax.Array) -> jax.Array:
         """Integer bucket coordinates, (..., m) int32."""

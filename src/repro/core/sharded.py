@@ -67,6 +67,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro import compat
 from repro.obs import trace as otrace
 
+from .cp_fused import cp_join_budget
 from .estimator import solve_parameters
 from .hashing import ProjectionFamily
 
@@ -115,7 +116,8 @@ def _estimate_block(proj_blk, qp, gid0: int, n_valid: int):
     masked to +inf.  Same norm-trick + clamp as the ref estimate."""
     qn = jnp.sum(qp * qp, axis=-1, keepdims=True)  # (B, 1)
     xn = jnp.sum(proj_blk * proj_blk, axis=-1)  # (nl,)
-    d2p = jnp.maximum(qn + xn[None, :] - 2.0 * (qp @ proj_blk.T), 0.0)
+    d2p = jnp.maximum(qn + xn[None, :] - 2.0 * jnp.dot(
+        qp, proj_blk.T, precision=jax.lax.Precision.HIGHEST), 0.0)
     nl = proj_blk.shape[0]
     valid = (gid0 + jnp.arange(nl)) < n_valid
     return jnp.where(valid[None, :], d2p, jnp.inf)
@@ -301,7 +303,8 @@ def _join_block(a_pts, a_norm, a_key, a_sgid, b_pts, b_norm, b_key, b_sgid,
     nl = a_pts.shape[0]
     nt = nl // tile
     d2 = jnp.maximum(
-        a_norm[:, None] + b_norm[None, :] - 2.0 * (a_pts @ b_pts.T), 0.0)
+        a_norm[:, None] + b_norm[None, :] - 2.0 * jnp.dot(
+            a_pts, b_pts.T, precision=jax.lax.Precision.HIGHEST), 0.0)
     pv = ((a_sgid[:, None] < n_valid) & (b_sgid[None, :] < n_valid)
           & (a_sgid[:, None] < b_sgid[None, :]))
 
@@ -415,12 +418,12 @@ class ShardedFlatIndex:
 
     Args:
       data: (n, d) float32 points.
-      shards: logical shard count P.  When P ≤ the visible device count
-        (and ``emulate`` is not forced) the index builds a 1-D submesh
+      shards: logical shard count P.  The index builds a 1-D submesh
         over the first P devices and runs the jit'd ``shard_map``
-        programs; otherwise it runs the emulated host path — identical
-        math over P logical blocks (so parity tests cover P ∈ {2,4,8}
-        even on one device).
+        programs; more shards than visible devices is an error.
+      emulate: run the emulated host path instead — identical math
+        over P logical blocks (so parity tests cover P ∈ {2,4,8} even
+        on one device).
       m / seed / c: projection family size, seed, ANN ratio — same
         meaning as ``build_flat_index``.
       quant: None or "pq" — per-shard PQ codebooks + shard-local ADC
@@ -465,7 +468,12 @@ class ShardedFlatIndex:
         self._data_blocks = data_p.reshape(self.P, self.nl, self.d)
         self._proj_blocks = proj_p.reshape(self.P, self.nl, self.m)
 
-        self.emulated = bool(emulate) or self.P > len(jax.devices())
+        if self.P > len(jax.devices()) and not emulate:
+            raise ValueError(
+                f"{self.P} shards need {self.P} devices, only "
+                f"{len(jax.devices())} visible; pass emulate=True to run "
+                "the host-emulated shards instead")
+        self.emulated = bool(emulate)
         if self.emulated:
             self.mesh = None
         elif mesh is not None:
@@ -671,21 +679,22 @@ class ShardedFlatIndex:
             return (np.empty((0, 2), np.int32), np.empty((0,), np.float32),
                     np.zeros((self.P,), np.int64), 0)
         self._build_cp_layout()
+        kj = cp_join_budget(kk, self.n * (self.n - 1) // 2)
         if self.emulated or traced:
             fd, fi, fj, pair_counts, tp = self._cp_emulated(
-                kk, thresh2=thresh2, traced=traced)
+                kj, thresh2=thresh2, traced=traced)
         else:
             with self.mesh:
                 fd, fi, fj, pair_counts, tp = _cp_program(
-                    self._cp_data_sh, self._cp_key_sh, mesh=self.mesh, k=kk,
+                    self._cp_data_sh, self._cp_key_sh, mesh=self.mesh, k=kj,
                     axis=self.axis, n_valid=self.n, thresh2=float(thresh2),
                     tile=self.cp_tile_eff)
         fd = np.asarray(fd)
         fi = np.asarray(fi)
         fj = np.asarray(fj)
         # host re-verification, exactly like cp_fused_search: map sorted
-        # positions back through the permutation, recompute the winners
-        # subtract-then-norm, stable re-sort
+        # positions back through the permutation, recompute the pool
+        # subtract-then-norm, stable re-sort, keep the k best
         real = np.isfinite(fd) & (fi >= 0)
         ids_a = self.cp_order[fi[real]].astype(np.int64)
         ids_b = self.cp_order[fj[real]].astype(np.int64)
@@ -695,7 +704,7 @@ class ShardedFlatIndex:
                 - self._data_np[pairs[:, 1].astype(np.int64)])
         dists = np.sqrt(np.sum(diff.astype(np.float32) ** 2, axis=1)
                         ).astype(np.float32)
-        resort = np.argsort(dists, kind="stable")
+        resort = np.argsort(dists, kind="stable")[:kk]
         return (pairs[resort], dists[resort],
                 np.asarray(pair_counts, np.int64), int(tp))
 

@@ -488,9 +488,9 @@ class ShardedFlatBackend(BaseIndex):
     register with tile-level radius pruning on cross-shard tiles.
 
     options: ``shards`` (logical shard count; defaults to the visible
-    device count), ``emulate`` (force the host-emulated multi-shard
-    path — used when shards > devices, e.g. parity tests on one
-    device), ``cp_gamma`` / ``rerank`` / ``force`` as on ``flat``.
+    device count, and more than that is an error), ``emulate`` (the
+    host-emulated multi-shard path — parity tests on one device),
+    ``cp_gamma`` / ``rerank`` / ``force`` as on ``flat``.
 
     WorkStats: summed counters match the single-device run
     (candidates_selected sums shard survivor counts = realized T·B;

@@ -5,7 +5,7 @@ kernels:
   project_dist  — fused ESTIMATE: x@A then ||·-q'||², projection stays in VMEM
   topk          — streaming answer top-k (selection network, k ≤ 128)
   select        — radius-threshold SELECT: Eq. 9-seeded r·c^i ladder +
-                  bisection + tile-local cumsum compaction; handles the
+                  bisection + per-tile matmul-rank packing; handles the
                   T = βn + k candidate budget without O(n·T) sort work
   verify        — gather-free VERIFY: DMAs candidate rows HBM→VMEM
                   tile-by-tile, exact distances + streaming top-k in

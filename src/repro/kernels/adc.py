@@ -53,6 +53,7 @@ def adc_dist_kernel(codes_ref, lut_ref, o_ref, *, block_s: int):
         ).astype(jnp.float32)  # (bN, V)
         acc += jax.lax.dot_general(
             lut[:, t, :], onehot, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )  # (bB, bN)
     o_ref[...] += acc

@@ -31,10 +31,11 @@ tile masking:
 Unskipped tiles DMA their two row blocks HBM→VMEM, compute exact
 original-space distances (norm trick, MXU cross term), mask the lower
 triangle / diagonal / padding, and fold all bN² candidates into the
-running top-k via the same masked-argmin selection network as
-``verify.py``.  Work counters (pair distances computed, tiles pruned)
-stream through SMEM and are emitted with the answer, so WorkStats can
-report ``pairs_verified`` / ``tiles_pruned`` per query.
+running top-k via the selection network shared with ``topk.py`` (a
+tile with no pair under the current ub² skips the fold).  Work counters
+(pair distances computed, tiles pruned) stream through SMEM and are
+emitted with the answer, so WorkStats can report ``pairs_verified`` /
+``tiles_pruned`` per query.
 
 Exactness: pruning is the ONLY approximation.  Every unskipped pair is
 an exact float32 distance, and a pair is skipped only when its 1-D key
@@ -52,6 +53,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .topk import smallest_k
 
 __all__ = ["pair_join_kernel", "pair_join_pallas"]
 
@@ -107,6 +110,7 @@ def pair_join_kernel(key_lo_ref, key_hi_ref, data_ref,
         nj = jnp.sum(xj * xj, axis=1)  # (bN,)
         cross = jax.lax.dot_general(
             xi, xj, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)  # (bN, bN) on the MXU
         d2 = jnp.maximum(ni[:, None] + nj[None, :] - 2.0 * cross, 0.0)
 
@@ -126,50 +130,28 @@ def pair_join_kernel(key_lo_ref, key_hi_ref, data_ref,
         nver_hi_ref[0] = nver_hi_ref[0] + carry
 
         # fold the tile into the running top-k pair heap (ub register):
-        # merge pool = acc ++ flattened tile, masked-argmin extraction
-        flat = block_n * block_n
-        vals = jnp.concatenate(
-            [accv_ref[...], d2.reshape(1, flat)], axis=1)  # (1, k + bN²)
-        idxi = jnp.concatenate(
-            [acci_ref[...], jnp.where(valid, gi, -1).reshape(1, flat)],
-            axis=1)
-        idxj = jnp.concatenate(
-            [accj_ref[...], jnp.where(valid, gj, -1).reshape(1, flat)],
-            axis=1)
-
-        def _extract(s, carry):
-            vals, outv, outi, outj = carry
-            col = jnp.argmin(vals, axis=1)  # (1,)
-            rows = jax.lax.broadcasted_iota(jnp.int32, (1,), 0)
-            outv = jax.lax.dynamic_update_index_in_dim(
-                outv, vals[rows, col], s, axis=1)
-            outi = jax.lax.dynamic_update_index_in_dim(
-                outi, idxi[rows, col], s, axis=1)
-            outj = jax.lax.dynamic_update_index_in_dim(
-                outj, idxj[rows, col], s, axis=1)
-            hit = (jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-                   == col[:, None])
-            return jnp.where(hit, jnp.inf, vals), outv, outi, outj
-
-        outv = jnp.zeros((1, k), jnp.float32)
-        outi = jnp.zeros((1, k), jnp.int32)
-        outj = jnp.zeros((1, k), jnp.int32)
-        _, outv, outi, outj = jax.lax.fori_loop(
-            0, k, _extract, (vals, outv, outi, outj))
-        accv_ref[...] = outv
-        acci_ref[...] = outi
-        accj_ref[...] = outj
+        # pool = acc ++ tile row-major; a tile with no pair under the
+        # k-th leaves the heap as it is
+        @pl.when(jnp.min(d2) < ub2)
+        def _fold():
+            outv, (outi, outj) = smallest_k(
+                [(accv_ref[...], (acci_ref[...], accj_ref[...]), (1,)),
+                 (d2, (jnp.where(valid, gi, -1), jnp.where(valid, gj, -1)),
+                  (0, 1))], k, 1)
+            accv_ref[...] = outv
+            acci_ref[...] = outi
+            accj_ref[...] = outj
 
     @pl.when(last)
     def _emit():
         ov_ref[...] = accv_ref[...]
         oi_ref[...] = acci_ref[...]
         oj_ref[...] = accj_ref[...]
-        stats = jnp.zeros((1, 128), jnp.int32)
-        stats = stats.at[0, 0].set(nver_lo_ref[0])
-        stats = stats.at[0, 1].set(npru_ref[0])
-        stats = stats.at[0, 2].set(nver_hi_ref[0])
-        os_ref[...] = stats
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+        os_ref[...] = jnp.where(
+            lane == 0, nver_lo_ref[0],
+            jnp.where(lane == 1, npru_ref[0],
+                      jnp.where(lane == 2, nver_hi_ref[0], 0)))
 
 
 def pair_join_pallas(
@@ -250,7 +232,7 @@ def _pair_join_jit(
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # key_lo (n_ti,)
             pl.BlockSpec(memory_space=pltpu.SMEM),  # key_hi (n_ti,)
-            pl.BlockSpec(memory_space=pltpu.ANY),   # x stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # x stays in HBM
         ],
         out_specs=[
             pl.BlockSpec((1, k), lambda b, i: (0, 0)),

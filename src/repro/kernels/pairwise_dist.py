@@ -38,8 +38,8 @@ def pairwise_sq_dist_kernel(q_ref, x_ref, o_ref):
     qn = jnp.sum(q * q, axis=1, keepdims=True)  # (bB, 1)
     xn = jnp.sum(x * x, axis=1)  # (bN,)
     cross = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (bB, bN) on the MXU
+        q, x, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)  # (bB, bN) on the MXU
     o_ref[...] += qn + xn[None, :] - 2.0 * cross
 
     @pl.when(k == pl.num_programs(2) - 1)

@@ -37,8 +37,8 @@ def project_dist_kernel(x_ref, a_ref, qp_ref, o_ref, acc_ref):
     x = x_ref[...].astype(jnp.float32)  # (bN, bD)
     a = a_ref[...].astype(jnp.float32)  # (bD, m̂)
     acc_ref[...] += jax.lax.dot_general(
-        x, a, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+        x, a, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
     @pl.when(k == pl.num_programs(1) - 1)
     def _emit():
@@ -47,8 +47,9 @@ def project_dist_kernel(x_ref, a_ref, qp_ref, o_ref, acc_ref):
         pn = jnp.sum(proj * proj, axis=1)  # (bN,)
         qn = jnp.sum(qp * qp, axis=1, keepdims=True)  # (B̂, 1)
         cross = jax.lax.dot_general(
-            qp, proj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (B̂, bN)
+            qp, proj, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)  # (B̂, bN)
         o_ref[...] = jnp.maximum(qn + pn[None, :] - 2.0 * cross, 0.0)
 
 

@@ -13,6 +13,10 @@ import numpy as np
 __all__ = ["pairwise_sq_dist", "project_dist", "topk_smallest", "adc_dist",
            "radius_select", "verify_topk", "pair_join"]
 
+#: float32 matmuls run as float32: on a TPU the default precision takes
+#: one bf16 pass, which rounds distances and ids past 2⁸
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def pairwise_sq_dist(q: jax.Array, x: jax.Array) -> jax.Array:
     """Squared Euclidean distances between rows of q (B,d) and x (N,d).
@@ -30,7 +34,7 @@ def pairwise_sq_dist(q: jax.Array, x: jax.Array) -> jax.Array:
         return jnp.sum((x - q[:, None, :]) ** 2, axis=-1)
     qn = jnp.sum(q * q, axis=-1, keepdims=True)  # (B, 1)
     xn = jnp.sum(x * x, axis=-1)  # (N,)
-    d2 = qn + xn[None, :] - 2.0 * (q @ x.T)
+    d2 = qn + xn[None, :] - 2.0 * jnp.dot(q, x.T, precision=_HIGHEST)
     return jnp.maximum(d2, 0.0)
 
 
@@ -41,7 +45,8 @@ def project_dist(x: jax.Array, a: jax.Array, qp: jax.Array) -> jax.Array:
     Returns (B, N) float32.  Semantically pairwise_sq_dist(qp, x @ a) —
     the kernel's value is that x@a never round-trips through HBM.
     """
-    proj = jnp.asarray(x, jnp.float32) @ jnp.asarray(a, jnp.float32)  # (N, m)
+    proj = jnp.dot(jnp.asarray(x, jnp.float32), jnp.asarray(a, jnp.float32),
+                   precision=_HIGHEST)  # (N, m)
     return pairwise_sq_dist(qp, proj)
 
 

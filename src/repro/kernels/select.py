@@ -18,11 +18,12 @@ then needs no sort at all, only branch-free O(n) threshold passes:
                  T-th smallest value between two rungs;
   phases 1..I    bisection passes shrink the bracket: count(d ≤ mid)
                  vs T keeps the invariant count(lo) < T ≤ count(hi);
-  final phase    one pass compacts survivors (d ≤ hi) into a dense
-                 (B, T_pad) buffer: tile-local cumsum ranks each tile's
-                 survivors, a one-hot MXU contraction packs them to the
-                 tile front, and an SMEM write cursor per row appends
-                 the packed run at the row's next free slot.
+  final phase    one pass packs each tile's survivors (d ≤ hi) to the
+                 front of its own output tile (``pack_front``): a 0/1
+                 mask times a triangle of ones on the MXU ranks them,
+                 a one-hot moves each to its rank.  Outside the
+                 kernel the per-tile runs are joined into a dense
+                 (B, T_pad) buffer by their running survivor totals.
 
 The caller finishes with one top_k over the T_pad ≈ 1.1·T compacted
 columns (``ops.radius_select``), so total ordering work drops from
@@ -36,7 +37,7 @@ pathological tie cluster (> T_pad − T equal values straddling the T-th
 smallest) overflows the buffer, and overflow truncates in INDEX order —
 the dropped high-index survivors may be strictly nearer than kept ones,
 so an overflowed buffer is NOT a valid candidate set.  The kernel
-therefore returns the exact per-row survivor counts and the dispatch
+therefore yields the exact per-row survivor counts and the dispatch
 wrapper (``ops.radius_select``) reroutes any overflowed batch to the
 exact sort, keeping parity unconditional.
 """
@@ -49,26 +50,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["radius_select_kernel", "radius_select_pallas"]
+__all__ = ["radius_select_kernel", "radius_select_pallas", "pack_front",
+           "tile_ranks"]
 
 
 def radius_select_kernel(
-    tau0_ref, d_ref, ov_ref, oi_ref, oc_ref,
-    cnt_ref, lad_ref, lo_ref, hi_ref, dmax_ref, offs_ref, tot_ref,
-    *, T: int, T_pad: int, block_n: int, L: int, L0: int, c2: float,
-    iters: int, n_tiles: int, Bh: int,
+    tau0_ref, d_ref, ov_ref, oi_ref,
+    cnt_ref, lad_ref, lo_ref, hi_ref, dmax_ref,
+    *, T: int, block_n: int, L: int, L0: int, c2: float, iters: int,
+    n_tiles: int, Bh: int,
 ):
     p = pl.program_id(0)  # phase: 0 ladder, 1..iters bisect, last compact
     j = pl.program_id(1)  # tile along n
     last = n_tiles - 1
     d = d_ref[...]  # (Bh, bN), padding carries +inf
     real = d < jnp.inf
+    lane = jax.lax.broadcasted_iota(jnp.int32, (Bh, 128), 1)
+
+    def rung(l):  # ladder threshold l: τ0·c^{2(l−L0)}, squared units
+        return tau0_ref[:, :1] * (c2 ** (l - L0))
 
     @pl.when((p == 0) & (j == 0))
     def _init():
-        ov_ref[...] = jnp.full_like(ov_ref, jnp.inf)
-        oi_ref[...] = jnp.full_like(oi_ref, -1)
-        oc_ref[...] = jnp.zeros_like(oc_ref)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
         lad_ref[...] = jnp.zeros_like(lad_ref)
         dmax_ref[...] = jnp.zeros_like(dmax_ref)
@@ -76,33 +79,32 @@ def radius_select_kernel(
     # -- phase 0: count all L ladder rungs in one data pass ---------------
     @pl.when(p == 0)
     def _ladder():
-        tau0 = tau0_ref[:, :1]  # (Bh, 1) per-row Eq. 9 seed, squared units
-        cols = [
-            jnp.sum((d <= tau0 * (c2 ** (l - L0))) & real, axis=1,
-                    keepdims=True).astype(jnp.float32)
-            for l in range(L)
-        ]
-        tile_cnt = jnp.concatenate(cols, axis=1)  # (Bh, L): rung l in col l
-        lad_ref[...] += jnp.concatenate(
-            [tile_cnt, jnp.zeros((Bh, 128 - L), jnp.float32)], axis=1)
+        tile_cnt = jnp.zeros((Bh, 128), jnp.float32)  # rung l in lane l
+        for l in range(L):
+            c = jnp.sum(((d <= rung(l)) & real).astype(jnp.float32), axis=1,
+                        keepdims=True)
+            tile_cnt = jnp.where(lane == l, c, tile_cnt)
+        lad_ref[...] += tile_cnt
         dmax_ref[...] = jnp.maximum(
             dmax_ref[...],
             jnp.max(jnp.where(real, d, -jnp.inf), axis=1, keepdims=True))
 
         @pl.when(j == last)
         def _bracket():
-            cnts = lad_ref[:, :L]
-            ge = cnts >= T
-            any_ge = jnp.any(ge, axis=1, keepdims=True)
-            first = jnp.argmax(ge, axis=1)[:, None].astype(jnp.float32)
+            # counts grow with the rung, so the smallest rung holding >= T
+            # survivors is the number of rungs holding fewer (L: none)
+            below = (lane < L) & (lad_ref[...] < T)
+            first = jnp.sum(below.astype(jnp.float32), axis=1, keepdims=True)
+            any_ge = first < L
+            at_first = sum(jnp.where(first == l, rung(l), 0.0)
+                           for l in range(L))
+            below_first = sum(jnp.where(first == l + 1, rung(l), 0.0)
+                              for l in range(L))
             dmax = dmax_ref[:, :1]
-            # smallest rung holding >= T survivors; the data max rescues
-            # a seed so low the whole ladder undershoots
-            hi = jnp.where(any_ge, tau0 * c2 ** (first - L0), dmax)
-            hi = jnp.minimum(hi, dmax)  # and one so high rung 0 overshoots
-            lo = jnp.where(any_ge & (first > 0),
-                           tau0 * c2 ** (first - 1.0 - L0), 0.0)
-            lo = jnp.where(any_ge, lo, tau0 * c2 ** (L - 1.0 - L0))
+            # the data max rescues a seed so low the whole ladder
+            # undershoots, and one so high rung 0 overshoots
+            hi = jnp.minimum(jnp.where(any_ge, at_first, dmax), dmax)
+            lo = jnp.where(any_ge, below_first, rung(L - 1))
             lo = jnp.minimum(lo, hi)
             hi_ref[...] = jnp.broadcast_to(hi, hi_ref.shape)
             lo_ref[...] = jnp.broadcast_to(lo, lo_ref.shape)
@@ -125,45 +127,52 @@ def radius_select_kernel(
                                     jnp.broadcast_to(mid, lo_ref.shape))
             cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    # -- final phase: compact survivors (d <= hi) into (Bh, T_pad) --------
+    # -- final phase: pack each tile's survivors (d <= hi) to its front ----
     @pl.when(p == iters + 1)
     def _compact():
-        @pl.when(j == 0)
-        def _zero():
-            for b in range(Bh):
-                offs_ref[b] = 0
-                tot_ref[b] = 0
+        def group(g, _):  # 8 rows at a time bound the (8, bN, bN) one-hot
+            r = pl.multiple_of(g * 8, 8)
+            dg = d_ref[pl.ds(r, 8), :]
+            mask = (dg <= hi_ref[pl.ds(r, 8), :1]) & (dg < jnp.inf)
+            vals, src, keep = pack_front(dg, mask)
+            ov_ref[pl.ds(r, 8), :] = jnp.where(keep, vals, jnp.inf)
+            oi_ref[pl.ds(r, 8), :] = jnp.where(keep, j * block_n + src, -1)
+            return 0
 
-        mask = (d <= hi_ref[:, :1]) & real
-        pos = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1  # tile-local rank
-        cnt_tile = pos[:, -1] + 1  # (Bh,) survivors in this tile
-        gidx = (j * block_n
-                + jax.lax.broadcasted_iota(jnp.int32, (Bh, block_n), 1)
-                ).astype(jnp.float32)
-        # pack survivors to the tile front: one-hot (src → rank) matmul
-        # carries values and indices together on the MXU
-        dst = jax.lax.broadcasted_iota(jnp.int32, (Bh, block_n, block_n), 2)
-        onehot = (mask[:, :, None] & (pos[:, :, None] == dst)
-                  ).astype(jnp.float32)  # (Bh, src, dst)
-        packed = jnp.stack([jnp.where(mask, d, 0.0), gidx], axis=1)
-        comp = jax.lax.dot_general(
-            packed, onehot, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)  # (Bh, 2, bN)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (Bh, block_n), 1)
-        keep = lane < cnt_tile[:, None]
-        cvals = jnp.where(keep, comp[:, 0, :], jnp.inf)
-        cidx = jnp.where(keep, comp[:, 1, :].astype(jnp.int32), -1)
-        for b in range(Bh):
-            off = jnp.minimum(offs_ref[b], T_pad)  # overflow clamps in-bounds
-            ov_ref[b, pl.ds(off, block_n)] = cvals[b]
-            oi_ref[b, pl.ds(off, block_n)] = cidx[b]
-            offs_ref[b] = off + cnt_tile[b]
-            tot_ref[b] = tot_ref[b] + cnt_tile[b]
+        jax.lax.fori_loop(0, Bh // 8, group, 0)
 
-        @pl.when(j == last)
-        def _emit():
-            counts = jnp.stack([tot_ref[b] for b in range(Bh)])[:, None]
-            oc_ref[...] = jnp.broadcast_to(counts, oc_ref.shape)
+
+def tile_ranks(mask: jax.Array) -> jax.Array:
+    """Per row, how many set entries precede each lane: an exclusive
+    running count, as a 0/1 mask times a strictly upper triangle of
+    ones — exact on the MXU (inputs 0/1, sums ≤ lanes, float32
+    accumulation)."""
+    w = mask.shape[1]
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
+           < jax.lax.broadcasted_iota(jnp.int32, (w, w), 1)
+           ).astype(jnp.float32)
+    return jnp.dot(mask.astype(jnp.float32), tri,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def pack_front(vals: jax.Array, mask: jax.Array):
+    """Move each row's masked entries of ``vals`` (R, W) to the row's
+    front, in lane order.  Returns (packed vals, their source lanes,
+    keep) where ``keep`` marks the first count(row) lanes; lanes past
+    it hold zeros."""
+    R, W = vals.shape
+    rank = tile_ranks(mask)
+    # one-hot (row, dst, src) moves survivor src to slot rank[src];
+    # one term per slot, so the masked sums are exact
+    dst = jax.lax.broadcasted_iota(jnp.int32, (R, W, W), 1)
+    src = jax.lax.broadcasted_iota(jnp.int32, (R, W, W), 2)
+    onehot = mask[:, None, :] & (rank[:, None, :] == dst)
+    packed = jnp.sum(jnp.where(onehot, vals[:, None, :], 0.0), axis=2)
+    lanes = jnp.sum(jnp.where(onehot, src, 0), axis=2)
+    keep = (jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
+            < jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True))
+    return packed, lanes, keep
 
 
 @functools.partial(
@@ -220,39 +229,59 @@ def radius_select_pallas(
             jnp.maximum(jnp.asarray(tau0, jnp.float32), 1e-30)[:, None],
             (B, 128)))
     n_tiles = Np // bN
-    T_out = T_pad + bN  # margin so the last window write stays in-bounds
+    P = iters + 2
     kern = functools.partial(
-        radius_select_kernel, T=T, T_pad=T_pad, block_n=bN, L=L, L0=L // 2,
+        radius_select_kernel, T=T, block_n=bN, L=L, L0=L // 2,
         c2=c2, iters=iters, n_tiles=n_tiles, Bh=Bh)
-    vals, idx, cnt = pl.pallas_call(
+    # the packed tiles are written only in the last phase; before it the
+    # output block index stays put, so nothing is written back early
+    out_tile = pl.BlockSpec(
+        (Bh, bN), lambda p, j: (0, jnp.where(p == P - 1, j, 0)))
+    vals_t, idx_t = pl.pallas_call(
         kern,
-        grid=(iters + 2, n_tiles),
+        grid=(P, n_tiles),
         in_specs=[
             pl.BlockSpec((Bh, 128), lambda p, j: (0, 0)),
             pl.BlockSpec((Bh, bN), lambda p, j: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((Bh, T_out), lambda p, j: (0, 0)),
-            pl.BlockSpec((Bh, T_out), lambda p, j: (0, 0)),
-            pl.BlockSpec((Bh, 128), lambda p, j: (0, 0)),
-        ],
+        out_specs=[out_tile, out_tile],
         out_shape=[
-            jax.ShapeDtypeStruct((Bh, T_out), jnp.float32),
-            jax.ShapeDtypeStruct((Bh, T_out), jnp.int32),
-            jax.ShapeDtypeStruct((Bh, 128), jnp.int32),
+            jax.ShapeDtypeStruct((Bh, Np), jnp.float32),
+            jax.ShapeDtypeStruct((Bh, Np), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((Bh, 128), jnp.float32),  # bisection count
-            pltpu.VMEM((Bh, 128), jnp.float32),  # ladder counts (col l)
+            pltpu.VMEM((Bh, 128), jnp.float32),  # ladder counts (lane l)
             pltpu.VMEM((Bh, 128), jnp.float32),  # bracket lo
             pltpu.VMEM((Bh, 128), jnp.float32),  # bracket hi
             pltpu.VMEM((Bh, 128), jnp.float32),  # running data max
-            pltpu.SMEM((Bh,), jnp.int32),        # per-row write cursor
-            pltpu.SMEM((Bh,), jnp.int32),        # per-row survivor total
         ],
         interpret=interpret,
     )(t0, dp)
-    return vals[:B, :T_pad], idx[:B, :T_pad], cnt[:B, 0]
+    return _concat_tiles(vals_t[:B], idx_t[:B], bN, T_pad)
+
+
+def _concat_tiles(vals_t, idx_t, bN: int, T_pad: int):
+    """Join each row's front-packed tile runs into its first T_pad slots.
+
+    vals_t / idx_t: (B, n_tiles·bN), tile t's survivors packed to the
+    front of its bN columns, (+inf, -1) behind them.  Slot s of a row
+    is read from the tile whose running survivor total first exceeds s.
+    """
+    B, Np = idx_t.shape
+    per_tile = jnp.sum(idx_t.reshape(B, Np // bN, bN) >= 0, axis=2,
+                       dtype=jnp.int32)  # (B, n_tiles)
+    upto = jnp.cumsum(per_tile, axis=1)  # survivors in tiles 0..t
+    count = upto[:, -1]
+    slot = jnp.arange(T_pad, dtype=jnp.int32)
+    tile = jax.vmap(lambda u: jnp.searchsorted(u, slot, side="right"))(upto)
+    tile = jnp.minimum(tile, Np // bN - 1).astype(jnp.int32)
+    start = jnp.take_along_axis(upto - per_tile, tile, axis=1)
+    col = tile * bN + slot[None, :] - start
+    live = slot[None, :] < count[:, None]
+    vals = jnp.where(live, jnp.take_along_axis(vals_t, col, axis=1), jnp.inf)
+    idx = jnp.where(live, jnp.take_along_axis(idx_t, col, axis=1), -1)
+    return vals, idx, count
 
 
 def _ceil_mult(v: int, m: int) -> int:

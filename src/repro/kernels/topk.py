@@ -4,13 +4,20 @@ PM-LSH's SELECT step takes the T = βn + k projected-nearest candidates.
 XLA's native `lax.top_k` is fine when the full (B, N) distance row fits
 HBM, but streaming selection fused after the distance tiles avoids a
 second pass.  This kernel demonstrates the streaming pattern: the grid
-walks N tiles; a VMEM scratch carries the running (B, k) best values +
-indices; each step merges the tile via k rounds of masked argmin
-(selection network — regular, branch-free, TPU-friendly for k ≤ 128).
+walks N tiles; the resident (B, k) output block carries the running
+best values + indices; each step merges the tile via k rounds of masked
+first-minimum extraction (selection network — regular, branch-free,
+TPU-friendly for k ≤ 128).  A tile none of whose values beats the
+running k-th is skipped outright.
 
-Complexity per tile: k·(k + bN) compares on the VPU.  For the k ≤ 64,
-bN = 512 regime of PM-LSH queries this is ≈ 37K compare-ops per tile —
-noise next to the MXU distance work it fuses behind.
+Complexity per merged tile: k·(k + bN) compares on the VPU.  For the
+k ≤ 64, bN = 512 regime of PM-LSH queries this is ≈ 37K compare-ops per
+tile — noise next to the MXU distance work it fuses behind.
+
+``smallest_k`` is the selection network itself, shared with the verify
+and pair-join kernels.  It reads every pick out of the pool with masked
+reductions over the ``hit`` mask — the TPU compiler lowers no gather
+inside a kernel.
 """
 from __future__ import annotations
 
@@ -19,52 +26,90 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["topk_kernel", "topk_smallest_pallas"]
+__all__ = ["smallest_k", "topk_kernel", "topk_smallest_pallas"]
+
+_BIG = jnp.iinfo(jnp.int32).max  # order of an element already taken
 
 
-def topk_kernel(d_ref, ov_ref, oi_ref, accv_ref, acci_ref, *, k: int, block_n: int):
+def _row_major(shape, axes) -> jax.Array:
+    """Position of each element within its pool: row-major over ``axes``."""
+    pos = jnp.zeros(shape, jnp.int32)
+    stride = 1
+    for ax in reversed(axes):
+        pos = pos + stride * jax.lax.broadcasted_iota(jnp.int32, shape, ax)
+        stride *= shape[ax]
+    return pos
+
+
+def smallest_k(pieces, k: int, rows: int):
+    """The k smallest of a pool, ascending, with their payloads.
+
+    ``pieces`` is a sequence of ``(vals, payloads, axes)``; the pool of
+    output row r is the concatenation, in piece order, of each piece's
+    elements of row r taken row-major over ``axes``: ``(1,)`` for a
+    (rows, C) piece, ``(0, 1)`` for one 2-D piece when rows == 1.
+    ``payloads`` are int32 arrays shaped like ``vals`` (ids) that travel
+    with the picks.  Ties go to the earlier pool element, as with
+    ``lax.top_k`` over the concatenation, and each element is taken at
+    most once.  Returns (vals (rows, k) float32, [payload (rows, k)]).
+    """
+    npay = len(pieces[0][1])
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1)
+    vals = [v for v, _, _ in pieces]
+    orders = [_row_major(v.shape, axes) for v, _, axes in pieces]
+
+    def round_(s, carry):
+        vals, orders, outv, outp = carry
+        gmin = functools.reduce(jnp.minimum, [
+            jnp.min(v, axis=axes, keepdims=True)
+            for v, (_, _, axes) in zip(vals, pieces)])  # (rows, 1)
+        taken = jnp.zeros((rows, 1), jnp.bool_)
+        picked = [jnp.zeros((rows, 1), jnp.int32) for _ in range(npay)]
+        new_vals, new_orders = [], []
+        for v, o, (_, pays, axes) in zip(vals, orders, pieces):
+            first = jnp.min(jnp.where(v == gmin, o, _BIG), axis=axes,
+                            keepdims=True)  # earliest live minimum
+            mine = ~taken & (first < _BIG)  # this piece supplies the pick
+            hit = mine & (o == first)
+            picked = [acc + jnp.sum(jnp.where(hit, p, 0), axis=axes,
+                                    keepdims=True)
+                      for acc, p in zip(picked, pays)]
+            taken = taken | mine
+            new_vals.append(jnp.where(hit, jnp.inf, v))
+            new_orders.append(jnp.where(hit, _BIG, o))
+        at = lane == s
+        outv = jnp.where(at, gmin, outv)
+        outp = [jnp.where(at, p, out) for p, out in zip(picked, outp)]
+        return new_vals, new_orders, outv, outp
+
+    outv = jnp.zeros((rows, k), jnp.float32)
+    outp = [jnp.zeros((rows, k), jnp.int32) for _ in range(npay)]
+    _, _, outv, outp = jax.lax.fori_loop(0, k, round_,
+                                         (vals, orders, outv, outp))
+    return outv, outp
+
+
+def topk_kernel(d_ref, ov_ref, oi_ref, *, k: int, block_n: int):
     j = pl.program_id(0)
 
     @pl.when(j == 0)
     def _init():
-        accv_ref[...] = jnp.full_like(accv_ref, jnp.inf)
-        acci_ref[...] = jnp.zeros_like(acci_ref)
+        ov_ref[...] = jnp.full_like(ov_ref, jnp.inf)
+        oi_ref[...] = jnp.zeros_like(oi_ref)
 
     d = d_ref[...].astype(jnp.float32)  # (B, bN)
-    base = j * block_n
     B, bN = d.shape
-    gidx = base + jax.lax.broadcasted_iota(jnp.int32, (B, bN), 1)
+    accv = ov_ref[...]  # running top-k, ascending: last column is the k-th
 
-    # merge pool = running top-k ++ tile
-    vals = jnp.concatenate([accv_ref[...], d], axis=1)  # (B, k+bN)
-    idxs = jnp.concatenate([acci_ref[...], gidx], axis=1)
-
-    def extract(s, carry):
-        vals, idxs, outv, outi = carry
-        col = jnp.argmin(vals, axis=1)  # (B,)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (B,), 0)
-        v = vals[rows, col]
-        i = idxs[rows, col]
-        outv = jax.lax.dynamic_update_index_in_dim(outv, v, s, axis=1)
-        outi = jax.lax.dynamic_update_index_in_dim(outi, i, s, axis=1)
-        onehot = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1) == col[:, None]
-        vals = jnp.where(onehot, jnp.inf, vals)
-        return vals, idxs, outv, outi
-
-    outv = jnp.zeros((B, k), jnp.float32)
-    outi = jnp.zeros((B, k), jnp.int32)
-    _, _, outv, outi = jax.lax.fori_loop(
-        0, k, extract, (vals, idxs, outv, outi)
-    )
-    accv_ref[...] = outv
-    acci_ref[...] = outi
-
-    @pl.when(j == pl.num_programs(0) - 1)
-    def _emit():
-        ov_ref[...] = accv_ref[...]
-        oi_ref[...] = acci_ref[...]
+    @pl.when(jnp.any(jnp.min(d, axis=1, keepdims=True)
+                     < jnp.max(accv, axis=1, keepdims=True)))
+    def _merge():
+        gidx = j * block_n + jax.lax.broadcasted_iota(jnp.int32, (B, bN), 1)
+        outv, (outi,) = smallest_k(
+            [(accv, (oi_ref[...],), (1,)), (d, (gidx,), (1,))], k, B)
+        ov_ref[...] = outv
+        oi_ref[...] = outi
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
@@ -103,10 +148,6 @@ def topk_smallest_pallas(
         out_shape=[
             jax.ShapeDtypeStruct((Bh, k), jnp.float32),
             jax.ShapeDtypeStruct((Bh, k), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((Bh, k), jnp.float32),
-            pltpu.VMEM((Bh, k), jnp.int32),
         ],
         interpret=interpret,
     )(dp)

@@ -8,12 +8,19 @@ distance reduction ever runs.  At T ≈ 0.1n that round-trip is ~3× the
 verify stage's unavoidable traffic and dominates the query's HBM bytes.
 
 This kernel never materializes the candidate tensor: the grid walks
-(query row, candidate tile); each step DMAs the tile's bT rows from the
-HBM-resident data array straight into a VMEM scratch, computes exact
-squared distances against the resident query row (norm trick, MXU
-cross term), and folds them into a running (1, k) top-k in VMEM via the
-same masked-argmin selection network as ``topk.py``.  Gathered rows
-live only in VMEM; HBM sees exactly one read of each candidate row.
+(block of 8 query rows, candidate tile); each step DMAs the tile's
+8·bT rows from the HBM-resident data array straight into a VMEM
+scratch, computes exact squared distances against the resident query
+rows (direct difference, as the oracle), and folds them into the
+running (8, k) top-k via the selection network shared with ``topk.py``.
+Gathered rows live only in VMEM.
+
+The rows are copied out of an (n, 1, d) view of the data: the TPU
+compiler copies only whole tiles, and the (n, d) array is tiled 8 rows
+deep.  XLA lays the view out one row per tile row, unpadded, so every
+call first copies the whole data array once (1 GB at n = 1M, d = 256;
+``tests/test_tpu_compile.py`` pins its size).  Past that copy, HBM sees
+one read of each candidate row.
 
 Padding contract: candidate ids < 0 are placeholders — their distance
 is +inf and they can only surface in the answer as (-1, inf) when a row
@@ -28,74 +35,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .topk import smallest_k
+
 __all__ = ["verify_topk_kernel", "verify_topk_pallas"]
 
 
-def verify_topk_kernel(q_ref, cand_ref, data_ref, ov_ref, oi_ref,
-                       rows_ref, accv_ref, acci_ref, sem,
-                       *, k: int, block_t: int, d: int):
+def verify_topk_kernel(q_ref, cid_ref, cand_ref, data_ref, ov_ref, oi_ref,
+                       rows_ref, sem, *, block_t: int, rows: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        accv_ref[...] = jnp.full_like(accv_ref, jnp.inf)
-        acci_ref[...] = jnp.full_like(acci_ref, -1)
+        ov_ref[...] = jnp.full_like(ov_ref, jnp.inf)
+        oi_ref[...] = jnp.full_like(oi_ref, -1)
 
-    cand = cand_ref[...]  # (1, bT) int32 ids into data, -1 = padding
+    # gather the tile's candidate rows HBM → VMEM, one (1, d) row per
+    # copy (padding ids < 0 copy nothing and are masked below); start
+    # every copy of the step, then drain
+    def each_copy(op):
+        for r in range(rows):
+            def body(i, _, r=r):
+                c = cid_ref[r, i]
 
-    # gather the tile's candidate rows HBM → VMEM (padding reads row 0,
-    # discarded by the mask below); start all copies, then drain
-    def _start(i, _):
-        idx = jnp.maximum(cand[0, i], 0)
-        pltpu.make_async_copy(data_ref.at[idx], rows_ref.at[i],
-                              sem.at[i]).start()
-        return 0
+                @pl.when(c >= 0)
+                def _():
+                    op(pltpu.make_async_copy(
+                        data_ref.at[c], rows_ref.at[r * block_t + i],
+                        sem.at[r]))
 
-    def _wait(i, _):
-        idx = jnp.maximum(cand[0, i], 0)
-        pltpu.make_async_copy(data_ref.at[idx], rows_ref.at[i],
-                              sem.at[i]).wait()
-        return 0
+                return 0
 
-    jax.lax.fori_loop(0, block_t, _start, 0)
-    jax.lax.fori_loop(0, block_t, _wait, 0)
+            jax.lax.fori_loop(0, block_t, body, 0)
 
-    x = rows_ref[...].astype(jnp.float32)  # (bT, d)
-    q = q_ref[...].astype(jnp.float32)  # (1, d)
-    xn = jnp.sum(x * x, axis=1)  # (bT,)
-    qn = jnp.sum(q * q, axis=1)  # (1,)
-    cross = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (1, bT) on the MXU
-    d2 = jnp.maximum(qn[:, None] + xn[None, :] - 2.0 * cross, 0.0)
-    d2 = jnp.where(cand < 0, jnp.inf, d2)  # (1, bT)
+    each_copy(lambda cp: cp.start())
+    each_copy(lambda cp: cp.wait())
 
-    # merge pool = running top-k ++ tile (masked-argmin selection network)
-    vals = jnp.concatenate([accv_ref[...], d2], axis=1)  # (1, k+bT)
-    idxs = jnp.concatenate([acci_ref[...], cand], axis=1)
+    d = q_ref.shape[1]
+    x = rows_ref[...].reshape(rows, block_t, d)
+    q = q_ref[...].astype(jnp.float32)  # (rows, d)
+    # the direct difference form, as the oracle: no norm-trick
+    # cancellation between near-duplicates
+    d2 = jnp.sum((x - q[:, None, :]) ** 2, axis=-1)  # (rows, bT)
+    cand = cand_ref[...]  # (rows, bT) int32 ids into data, -1 = padding
+    d2 = jnp.where(cand < 0, jnp.inf, d2)
+    accv = ov_ref[...]  # running top-k, ascending
 
-    def _extract(s, carry):
-        vals, idxs, outv, outi = carry
-        col = jnp.argmin(vals, axis=1)  # (1,)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (1,), 0)
-        v = vals[rows, col]
-        i = idxs[rows, col]
-        outv = jax.lax.dynamic_update_index_in_dim(outv, v, s, axis=1)
-        outi = jax.lax.dynamic_update_index_in_dim(outi, i, s, axis=1)
-        hit = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1) == col[:, None]
-        return jnp.where(hit, jnp.inf, vals), idxs, outv, outi
-
-    outv = jnp.zeros((1, k), jnp.float32)
-    outi = jnp.zeros((1, k), jnp.int32)
-    _, _, outv, outi = jax.lax.fori_loop(
-        0, k, _extract, (vals, idxs, outv, outi))
-    accv_ref[...] = outv
-    acci_ref[...] = outi
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _emit():
-        ov_ref[...] = accv_ref[...]
-        oi_ref[...] = acci_ref[...]
+    @pl.when(jnp.any(jnp.min(d2, axis=1, keepdims=True)
+                     < jnp.max(accv, axis=1, keepdims=True)))
+    def _merge():
+        outv, (outi,) = smallest_k(
+            [(accv, (oi_ref[...],), (1,)), (d2, (cand,), (1,))],
+            ov_ref.shape[1], rows)
+        ov_ref[...] = outv
+        oi_ref[...] = outi
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_t", "interpret"))
@@ -111,7 +103,8 @@ def verify_topk_pallas(
     """Exact-verify candidates and answer: fused gather + distance + top-k.
 
     Args:
-      data: (n, d) float32 points, resident in HBM (never tiled whole).
+      data: (n, d) float32 points, resident in HBM; read through an
+        (n, 1, d) relayout copy made on every call.
       q: (B, d) float32 queries.
       cand: (B, Tc) int32 candidate ids per query; -1 marks padding.
       k: answer size, ≤ min(128, Tc) (same selection-network regime as
@@ -132,36 +125,45 @@ def verify_topk_pallas(
             "network is O(k²) — route large-k selection through "
             "radius_select instead")
     # k > Tc is legal: short rows answer with (-1, inf) padding slots
+    R = 8  # query rows per grid step: one full sublane tile
     bT = min(block_t, _ceil_mult(max(Tc, 1), 128))
     Tp = _ceil_mult(max(Tc, 1), bT)
-    cp = jnp.full((B, Tp), -1, jnp.int32).at[:, :Tc].set(
+    Bp = _ceil_mult(B, R)
+    cp = jnp.full((Bp, Tp), -1, jnp.int32).at[:B, :Tc].set(
         jnp.asarray(cand, jnp.int32))
-    kern = functools.partial(verify_topk_kernel, k=k, block_t=bT, d=d)
+    qp = jnp.zeros((Bp, d), jnp.float32).at[:B].set(
+        jnp.asarray(q, jnp.float32))
+    # (n, 1, d): one point per leading index, so a single-row copy is a
+    # whole tile of the HBM layout.  Not a bitcast: XLA relays the data
+    # out from 8-row to 1-row tiles, a copy of the whole array per call
+    rows3 = jnp.asarray(data, jnp.float32).reshape(n, 1, d)
+    kern = functools.partial(verify_topk_kernel, block_t=bT, rows=R)
     vals, idx = pl.pallas_call(
         kern,
-        grid=(B, Tp // bT),
+        grid=(Bp // R, Tp // bT),
         in_specs=[
-            pl.BlockSpec((1, d), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, bT), lambda b, j: (b, j)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # data stays in HBM
+            pl.BlockSpec((R, d), lambda b, j: (b, 0)),
+            pl.BlockSpec((R, bT), lambda b, j: (b, j),
+                         memory_space=pltpu.SMEM),  # ids for the copies
+            pl.BlockSpec((R, bT), lambda b, j: (b, j)),  # ids as a vector
+            pl.BlockSpec(memory_space=pl.ANY),  # data stays in HBM
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, k), lambda b, j: (b, 0)),
+            pl.BlockSpec((R, k), lambda b, j: (b, 0)),
+            pl.BlockSpec((R, k), lambda b, j: (b, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, k), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, k), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bT, d), jnp.float32),  # gathered candidate rows
-            pltpu.VMEM((1, k), jnp.float32),   # running top-k values
-            pltpu.VMEM((1, k), jnp.int32),     # running top-k ids
-            pltpu.SemaphoreType.DMA((bT,)),
+            pltpu.VMEM((R * bT, 1, d), jnp.float32),  # gathered rows
+            pltpu.SemaphoreType.DMA((R,)),
         ],
         interpret=interpret,
-    )(jnp.asarray(q, jnp.float32), cp, jnp.asarray(data, jnp.float32))
-    return vals, idx
+    )(qp, cp, cp, rows3)
+    vals, idx = vals[:B], idx[:B]
+    return vals, jnp.where(jnp.isinf(vals), -1, idx)
 
 
 def _ceil_mult(v: int, m: int) -> int:

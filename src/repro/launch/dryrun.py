@@ -9,8 +9,9 @@ Per cell it records:
   * compiled.cost_analysis()    — HLO FLOPs + bytes accessed
   * collective bytes parsed from the optimized HLO (all-gather,
     all-reduce, reduce-scatter, all-to-all, collective-permute)
-  * the three roofline terms for TPU v5e (197 TF/s bf16, 819 GB/s HBM,
-    ~50 GB/s/link ICI) and MODEL_FLOPS/HLO_FLOPs utilization.
+  * the three roofline terms for TPU v5e (peaks from
+    ``repro.obs.roofline.PEAKS``, ~50 GB/s/link ICI) and
+    MODEL_FLOPS/HLO_FLOPs utilization.
 """
 from __future__ import annotations
 
@@ -28,9 +29,11 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 
+from repro.obs.roofline import PEAKS
+
 # hardware constants (TPU v5e)
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
+PEAK_FLOPS = PEAKS["TPU v5 lite"].peak_flops  # bf16 per chip
+HBM_BW = PEAKS["TPU v5 lite"].peak_bw  # bytes/s per chip
 ICI_BW = 50e9  # bytes/s per link
 
 _DTYPE_BYTES = {

@@ -20,16 +20,16 @@ Conventions:
     AI below the ridge → the op is memory-bound, its attainable
     ceiling is AI · peak_bw; above → compute-bound at peak_flops.
 
-Peaks default to rough public numbers per ``jax.default_backend()``
-kind and exist to *classify* (the bound and a fraction-of-peak
-estimate), not to certify — override via :func:`set_peaks` for a real
-machine.
+Peaks come from one table, :data:`PEAKS`, keyed by the device kind JAX
+reports (``jax.devices()[0].device_kind``) and citing its source; a
+device missing from it is an error, not a default.  :func:`set_peaks`
+pins measured peaks for a process.
 """
 from __future__ import annotations
 
 import dataclasses
 
-__all__ = ["KernelCost", "DevicePeaks", "device_kind", "get_peaks",
+__all__ = ["KernelCost", "DevicePeaks", "PEAKS", "device_kind", "get_peaks",
            "set_peaks", "pairwise_sq_dist_cost", "project_dist_cost",
            "adc_dist_cost", "topk_cost", "radius_select_cost",
            "verify_topk_cost", "pair_join_cost", "achieved"]
@@ -69,35 +69,36 @@ class DevicePeaks:
         return self.peak_flops / self.peak_bw
 
 
-#: rough public-spec numbers — enough to place an op on the roofline;
-#: override with set_peaks() when certifying a specific machine
-_DEFAULT_PEAKS = {
-    # ~8-core AVX2 server slice: 8c · 2.5GHz · 16 f32 FLOP/cycle; DDR4
+#: per-chip peaks keyed by ``jax.devices()[0].device_kind``
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 and
+    # 819 GB/s of HBM bandwidth per chip
+    "TPU v5 lite": DevicePeaks("TPU v5 lite", 1.97e14, 8.19e11),
+    # nominal 8-core AVX2 host (8 cores · 2.5 GHz · 16 f32 FLOP/cycle,
+    # DDR4): the row CPU-only runs and the tests classify against
     "cpu": DevicePeaks("cpu", 3.2e11, 4.0e10),
-    # A100-class accelerator
-    "gpu": DevicePeaks("gpu", 1.95e13, 1.55e12),
-    # TPU v4-class MXU + HBM2e
-    "tpu": DevicePeaks("tpu", 2.75e14, 1.2e12),
 }
 _PEAKS_OVERRIDE: DevicePeaks | None = None
 
 
 def device_kind() -> str:
-    """The jax backend kind ("cpu" | "gpu" | "tpu"), "cpu" if jax is
-    unimportable (pure-numpy contexts)."""
-    try:
-        import jax
+    """The device kind JAX reports for the first device, e.g.
+    "TPU v5 lite" or "cpu" — the key of :data:`PEAKS`."""
+    import jax
 
-        return str(jax.default_backend())
-    except Exception:  # pragma: no cover
-        return "cpu"
+    return str(jax.devices()[0].device_kind)
 
 
 def get_peaks(kind: str | None = None) -> DevicePeaks:
+    """Peaks of ``kind`` (default: this process's device).  Raises
+    ``KeyError`` for a device missing from :data:`PEAKS`."""
     if _PEAKS_OVERRIDE is not None:
         return _PEAKS_OVERRIDE
     kind = kind or device_kind()
-    return _DEFAULT_PEAKS.get(kind, _DEFAULT_PEAKS["cpu"])
+    if kind not in PEAKS:
+        raise KeyError(f"no peaks for device kind {kind!r}; add a row with "
+                       f"its source to repro.obs.roofline.PEAKS")
+    return PEAKS[kind]
 
 
 def set_peaks(peaks: DevicePeaks | None) -> None:

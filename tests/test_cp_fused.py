@@ -160,6 +160,27 @@ class TestEngineExactness:
         assert ratio_fused <= ratio_host + 1e-6
         assert ratio_fused < 4.0 and ratio_host < 4.0
 
+    def test_join_pool_keeps_pairs_float_error_reorders(self, monkeypatch):
+        """Deep twin, n = 16384, seed 9: the join's float32 norm-trick
+        distances rank a true top-10 pair past the 10th, so a 10-pair
+        pool loses it.  The default pool keeps it for the exact re-rank
+        and answers as a 64-pair pool does."""
+        import repro.core.cp_fused as cf
+        from benchmarks.datasets import make_dataset
+
+        x = make_dataset("deep", seed=9, n=16384)
+        kw = dict(c=1.5, m=15, force="ref")
+        got = cp_fused_search(x, 10, **kw)
+        monkeypatch.setattr(cf, "cp_join_budget",
+                            lambda k, n_pairs: min(64, n_pairs))
+        wide = cp_fused_search(x, 10, **kw)
+        monkeypatch.setattr(cf, "cp_join_budget",
+                            lambda k, n_pairs: min(k, n_pairs))
+        narrow = cp_fused_search(x, 10, **kw)
+        np.testing.assert_array_equal(got.pairs, wide.pairs)
+        np.testing.assert_array_equal(got.distances, wide.distances)
+        assert _pairset(narrow.pairs) != _pairset(wide.pairs)
+
     def test_duplicate_points(self):
         """Exact duplicates: the top pairs are the distance-0 ones."""
         x = _make(80, seed=11)
