@@ -85,13 +85,15 @@ def run(quick: bool = True):
             f"pipeline_ratio_n{n}", 0.0,
             "fused_over_unfused=%.3f;parity=%.3f" % (ratio, match)))
 
-    # stage breakdown: one traced fused query AFTER the timed loops
-    # (tracing runs the eager stage-by-stage twin — its per-stage wall
-    # split lands in the summary, never in the latencies above)
-    from repro.core.fused import fused_ann_query_traced
+    # host-step breakdown: one traced facade search AFTER the timed
+    # loops; it runs the same jitted program as the untraced calls, and
+    # the spans split its wall time into dispatch, wait and answer
+    from repro.index import IndexConfig, build_index
 
-    trace_probe("fused_query",
-                lambda: fused_ann_query_traced(index, q, k=k, T=T))
+    facade = build_index(data, IndexConfig(backend="flat",
+                                           options={"fused": True}))
+    facade.search(q, k=k)  # compile outside the traced call
+    trace_probe("fused_query", lambda: facade.search(q, k=k))
 
     publish_summary("query_pipeline", B=B, d=d, k=k, sizes=speedups,
                     gate="fused p50 < unfused p50 for n >= 32768")

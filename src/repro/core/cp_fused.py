@@ -35,6 +35,7 @@ from __future__ import annotations
 import dataclasses
 import math
 
+import jax
 import numpy as np
 
 from repro.obs import trace as otrace
@@ -124,49 +125,52 @@ def cp_fused_search(
     if kk == 0:
         return CpFusedResult(np.empty((0, 2), np.int32),
                              np.empty((0,), np.float32), 0, 0)
-    with otrace.span("cp.query", n=n, d=d, k=kk):
+    if key is None:
         with otrace.span("cp.project"):
-            if key is None:
-                # only the FIRST projection coordinate is needed; project
-                # with that one column rather than paying for the full
-                # m-dim family
-                family = ProjectionFamily.create(d, m, seed=seed)
-                key = data @ np.asarray(family.a)[:, 0]
-            key = np.asarray(key, dtype=np.float32).reshape(-1)
-        if key.shape[0] != n:
-            raise ValueError(f"key has {key.shape[0]} entries for n={n}")
+            # only the FIRST projection coordinate is needed; project
+            # with that one column rather than paying for the full
+            # m-dim family
+            family = ProjectionFamily.create(d, m, seed=seed)
+            key = data @ np.asarray(family.a)[:, 0]
+    key = np.asarray(key, dtype=np.float32).reshape(-1)
+    if key.shape[0] != n:
+        raise ValueError(f"key has {key.shape[0]} entries for n={n}")
 
-        with otrace.span("cp.sort"):
-            order = np.argsort(key, kind="stable")
-            xs, ks = data[order], key[order]
-        with otrace.span("cp.join"):
-            thresh2 = cp_threshold2(c, m, gamma)
-            d2, pi, pj, stats = kops.pair_join(
-                xs, ks, cp_join_budget(kk, n * (n - 1) // 2),
-                thresh2=thresh2, force=force, block_n=block_n)
-            d2 = np.asarray(d2)
-            pi = np.asarray(pi)
-            pj = np.asarray(pj)
-            stats = np.asarray(stats)
+    with otrace.span("cp.sort"):
+        order = np.argsort(key, kind="stable")
+    with otrace.span("cp.gather"):
+        xs, ks = data[order], key[order]
+    kj = cp_join_budget(kk, n * (n - 1) // 2)
+    if kops.pair_join_on_device(kj, force):
+        with otrace.span("cp.h2d"):  # the join kernel's upload, made visible
+            xs, ks = jax.device_put(xs), jax.device_put(ks)
+    with otrace.span("cp.join"):
+        d2, pi, pj, stats = kops.pair_join(
+            xs, ks, kj, thresh2=cp_threshold2(c, m, gamma), force=force,
+            block_n=block_n)
+        d2 = np.asarray(d2)
+        pi = np.asarray(pi)
+        pj = np.asarray(pj)
+        stats = np.asarray(stats)
 
-        with otrace.span("cp.reverify"):
-            real = pi >= 0
-            ids_a = order[pi[real]].astype(np.int64)
-            ids_b = order[pj[real]].astype(np.int64)
-            pairs = np.stack([np.minimum(ids_a, ids_b),
-                              np.maximum(ids_a, ids_b)],
-                             axis=1).astype(np.int32)
-            # the join ranks pairs by norm-trick distances (MXU form),
-            # which cancel catastrophically exactly where CP answers
-            # live — between near-duplicates.  Recompute the pool in
-            # the stable subtract-then-norm form (2k rows, negligible)
-            # and re-sort, so the k reported pairs and distances are
-            # what a direct verification gives.
-            diff = (data[pairs[:, 0].astype(np.int64)]
-                    - data[pairs[:, 1].astype(np.int64)])
-            dists = np.sqrt(np.sum(diff.astype(np.float32) ** 2, axis=1)
-                            ).astype(np.float32)
-            resort = np.argsort(dists, kind="stable")[:kk]
+    with otrace.span("cp.reverify"):
+        real = pi >= 0
+        ids_a = order[pi[real]].astype(np.int64)
+        ids_b = order[pj[real]].astype(np.int64)
+        pairs = np.stack([np.minimum(ids_a, ids_b),
+                          np.maximum(ids_a, ids_b)],
+                         axis=1).astype(np.int32)
+        # the join ranks pairs by norm-trick distances (MXU form),
+        # which cancel catastrophically exactly where CP answers
+        # live — between near-duplicates.  Recompute the pool in
+        # the stable subtract-then-norm form (2k rows, negligible)
+        # and re-sort, so the k reported pairs and distances are
+        # what a direct verification gives.
+        diff = (data[pairs[:, 0].astype(np.int64)]
+                - data[pairs[:, 1].astype(np.int64)])
+        dists = np.sqrt(np.sum(diff.astype(np.float32) ** 2, axis=1)
+                        ).astype(np.float32)
+        resort = np.argsort(dists, kind="stable")[:kk]
     return CpFusedResult(pairs=pairs[resort], distances=dists[resort],
                          pairs_verified=int(stats[0]),
                          tiles_pruned=int(stats[1]))

@@ -98,10 +98,11 @@ def answer_distances(data: jax.Array, ids: jax.Array,
     the returned floats to one reduction order at O(B·k·d) cost — noise
     next to the O(B·T·d) verify.
     """
-    rows = data[jnp.maximum(ids, 0)]  # (B, k, d)
-    d2 = jnp.sum((rows - q[:, None, :]) ** 2, axis=-1)
-    d2 = jnp.where(ids < 0, jnp.inf, d2)
-    return jnp.sqrt(jnp.maximum(d2, 0.0)).astype(jnp.float32)
+    with jax.named_scope("answer"):
+        rows = data[jnp.maximum(ids, 0)]  # (B, k, d)
+        d2 = jnp.sum((rows - q[:, None, :]) ** 2, axis=-1)
+        d2 = jnp.where(ids < 0, jnp.inf, d2)
+        return jnp.sqrt(jnp.maximum(d2, 0.0)).astype(jnp.float32)
 
 
 @partial(jax.jit, static_argnames=("k", "T", "use_kernels", "fused", "force",
@@ -153,21 +154,23 @@ def ann_query(
     q = jnp.asarray(q, jnp.float32)
     if q.ndim == 1:
         q = q[None]
-    qp = index.family.project(q)  # (B, m)
 
     # 1-2. estimate + select: projected distances, top-T smallest
-    d2p = kops.pairwise_sq_dist(qp, index.projected, force=force)  # (B, n)
-    _, cand = jax.lax.top_k(-d2p, T)  # (B, T) candidate ids
+    with jax.named_scope("estimate"):
+        qp = index.family.project(q)  # (B, m)
+        d2p = kops.pairwise_sq_dist(qp, index.projected, force=force)
+    with jax.named_scope("select"):
+        _, cand = jax.lax.top_k(-d2p, T)  # (B, T) candidate ids
 
     # 3. verify: exact distances on the candidate set, through the same
-    # kernel dispatch policy as the estimate (vmapped per-query rows)
-    cpts = index.data[cand]  # (B, T, d)
-    d2 = kops.pairwise_sq_dist(q, cpts, force=force)  # (B, T)
-
-    # 4. answer
-    negk, sel = jax.lax.top_k(-d2, k)
-    idx = jnp.take_along_axis(cand, sel, axis=1)
-    out = idx.astype(jnp.int32), jnp.sqrt(jnp.maximum(-negk, 0.0))
+    # kernel dispatch policy as the estimate (vmapped per-query rows),
+    # then 4. the answer's top-k
+    with jax.named_scope("verify"):
+        cpts = index.data[cand]  # (B, T, d)
+        d2 = kops.pairwise_sq_dist(q, cpts, force=force)  # (B, T)
+        negk, sel = jax.lax.top_k(-d2, k)
+        idx = jnp.take_along_axis(cand, sel, axis=1)
+        out = idx.astype(jnp.int32), jnp.sqrt(jnp.maximum(-negk, 0.0))
     if with_count:
         return out + (jnp.full((q.shape[0],), T, jnp.int32),)
     return out

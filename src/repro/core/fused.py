@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from .estimator import chi2_ppf
 from .flat_index import FlatIndex
 
-__all__ = ["fused_ann_query", "fused_ann_query_traced", "select_seed"]
+__all__ = ["fused_ann_query", "select_seed"]
 
 
 def select_seed(d2p: jax.Array, T: int, m: int | None) -> jax.Array:
@@ -89,66 +89,22 @@ def fused_ann_query(
     q = jnp.asarray(q, jnp.float32)
     if q.ndim == 1:
         q = q[None]
-    qp = index.family.project(q)  # (B, m)
 
     # 1. estimate: projected squared distances (Lemma 2)
-    d2p = kops.pairwise_sq_dist(qp, index.projected, force=force)  # (B, n)
+    with jax.named_scope("estimate"):
+        qp = index.family.project(q)  # (B, m)
+        d2p = kops.pairwise_sq_dist(qp, index.projected, force=force)
 
     # 2. select: radius-threshold selection seeded from Eq. 9
-    m = index.params.m if index.params is not None else index.m
-    tau0 = select_seed(d2p, T, m)
-    _, cand, cnt = kops.radius_select(d2p, T, tau0=tau0, force=force,
-                                      with_count=True)  # (B, T), (B,)
-
-    # 3-4. verify + answer: gather-free exact distances, streaming top-k
-    d2, idx = kops.verify_topk(index.data, q, cand, k, force=force)
-    out = idx.astype(jnp.int32), jnp.sqrt(jnp.maximum(d2, 0.0))
-    return out + (cnt,) if with_count else out
-
-
-def fused_ann_query_traced(
-    index: FlatIndex,
-    q: jax.Array,
-    *,
-    k: int,
-    T: int,
-    force: str | None = None,
-    with_count: bool = False,
-):
-    """Stage-by-stage eager twin of :func:`fused_ann_query` for tracing.
-
-    Identical math and answers, but each stage runs outside jit and is
-    wrapped in an ``ann.*`` span (with the per-kernel ``kernel.*``
-    spans from ``repro.kernels.ops`` nesting underneath), so a trace
-    shows where estimate/select/verify time actually goes.  Callers
-    (``FlatBackend._search``) route here only while a tracer is
-    enabled — the jit'd path above is untouched otherwise.  The select
-    span additionally records the batch's summed survivor count as
-    ``candidates_selected``.
-    """
-    from repro.kernels import ops as kops
-    from repro.obs import trace as otrace
-
-    tr = otrace.get_tracer()
-    q = jnp.asarray(q, jnp.float32)
-    if q.ndim == 1:
-        q = q[None]
-    with tr.span("ann.query", B=int(q.shape[0]), n=int(index.data.shape[0]),
-                 k=k, T=T):
-        with tr.span("ann.project"):
-            qp = otrace.block(index.family.project(q))
-        with tr.span("ann.estimate"):
-            d2p = kops.pairwise_sq_dist(qp, index.projected, force=force)
-        with tr.span("ann.select") as sp:
+    with jax.named_scope("select"):
+        with jax.named_scope("seed"):
             m = index.params.m if index.params is not None else index.m
             tau0 = select_seed(d2p, T, m)
-            _, cand, cnt = kops.radius_select(d2p, T, tau0=tau0, force=force,
-                                              with_count=True)
-            if sp is not None:
-                sp.attrs["candidates_selected"] = int(jnp.sum(cnt))
-        with tr.span("ann.verify"):
-            d2, idx = kops.verify_topk(index.data, q, cand, k, force=force)
-        with tr.span("ann.answer"):
-            out = otrace.block(idx.astype(jnp.int32),
-                               jnp.sqrt(jnp.maximum(d2, 0.0)))
+        _, cand, cnt = kops.radius_select(d2p, T, tau0=tau0, force=force,
+                                          with_count=True)  # (B, T), (B,)
+
+    # 3-4. verify + answer: gather-free exact distances, streaming top-k
+    with jax.named_scope("verify"):
+        d2, idx = kops.verify_topk(index.data, q, cand, k, force=force)
+        out = idx.astype(jnp.int32), jnp.sqrt(jnp.maximum(d2, 0.0))
     return out + (cnt,) if with_count else out

@@ -43,9 +43,7 @@ Both programs exist twice with identical math:
   * an EMULATED path — a host loop over logical shard blocks running
     the same per-shard jnp stage functions, with psum/pmax/all-gather
     replaced by exact host reductions.  It serves single-device runs
-    at any logical shard count and doubles as the obs traced twin
-    (``shard.select/exchange/verify/merge`` spans with modeled
-    exchange bytes), mirroring ``fused_ann_query_traced``.
+    at any logical shard count.
 
 Exactness of the threshold exchange: int sums (psum of counts) and
 float max (pmax) are associative bit-exactly, and the bisection state
@@ -65,7 +63,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat
-from repro.obs import trace as otrace
 
 from .cp_fused import cp_join_budget
 from .estimator import solve_parameters
@@ -107,7 +104,7 @@ def _device_put_sharded(arr: np.ndarray, mesh: Mesh, axis: str):
 
 # ---------------------------------------------------------------------------
 # per-shard ANN stage math (shared verbatim by the mesh program and the
-# emulated/traced path — parity between the two is parity of these)
+# emulated path — parity between the two is parity of these)
 # ---------------------------------------------------------------------------
 
 
@@ -584,92 +581,67 @@ class ShardedFlatIndex:
             NamedSharding(self.mesh, P(self.axis, None, None, None)))
 
     # the emulated path: the same stage math over logical shard blocks,
-    # with exact host reductions in place of the mesh collectives.  Also
-    # the obs traced twin (tracer=True adds shard.* spans).
-    def _query_emulated(self, qj, qp, *, k: int, T: int, traced: bool = False):
+    # with exact host reductions in place of the mesh collectives
+    def _query_emulated(self, qj, qp, *, k: int, T: int):
         from repro.kernels import ops as kops
-        from repro.obs import roofline
 
-        tr = otrace.get_tracer() if traced else None
-        sp = tr.span if tr is not None else otrace.span
         P_, nl = self.P, self.nl
-        B = int(qj.shape[0])
         cap = min(nl, T)
         pq = self.codecs is not None
         R_l = min(self._rerank_budget(k, T), cap) if pq else cap
         k_l = min(k, R_l if pq else cap)
 
-        with sp("shard.query", P=P_, B=B, n=self.n, k=k, T=T):
-            with sp("shard.estimate"):
-                d2ps = [_estimate_block(jnp.asarray(self._proj_blocks[p]),
-                                        qp, p * nl, self.n)
-                        for p in range(P_)]
-            with sp("shard.select", rounds=BISECT_ROUNDS) as s_sel:
-                row_max = [jnp.max(jnp.where(jnp.isfinite(d), d, 0.0), axis=1)
-                           for d in d2ps]
-                hi0 = row_max[0]
-                for r in row_max[1:]:
-                    hi0 = jnp.maximum(hi0, r)  # pmax
-                hi = jax.lax.bitcast_convert_type(hi0, jnp.int32)
-                lo = jnp.full_like(hi, -1)
-                for _ in range(BISECT_ROUNDS):
-                    mid = _bisect_mid(lo, hi)
-                    cnt = _count_le_bits(d2ps[0], mid)
-                    for d in d2ps[1:]:
-                        cnt = cnt + _count_le_bits(d, mid)  # psum
-                    lo, hi = _bisect_step(lo, hi, cnt, T)
-                tau = jax.lax.bitcast_convert_type(hi, jnp.float32)
-                cands, cnts = [], []
-                for p in range(P_):
-                    cand, cnt_loc = _compact_block(d2ps[p], tau, cap)
-                    cands.append(cand)
-                    cnts.append(cnt_loc)
-                if s_sel is not None:
-                    s_sel.attrs["candidates_selected"] = int(
-                        sum(int(jnp.sum(c)) for c in cnts))
-            with sp("shard.exchange",
-                    **roofline.shard_exchange_cost(
-                        P_, B, k_l, rounds=BISECT_ROUNDS).attrs()):
-                counts = jnp.stack(cnts)  # (P, B) — the counts all-gather
-            with sp("shard.verify"):
-                d2s, gids = [], []
-                for p in range(P_):
-                    cand = cands[p]
-                    if pq:
-                        lut = self.codecs[p].lookup_tables(qj)
-                        codes = jnp.asarray(self._codes_blocks[p])[
-                            jnp.maximum(cand, 0)]
-                        adc = kops.adc_dist(codes, lut, force=self.force)
-                        adc = jnp.where(cand < 0, jnp.inf, adc)
-                        _, rsel = jax.lax.top_k(-adc, R_l)
-                        cand = jnp.take_along_axis(cand, rsel, axis=1)
-                    d2l, locl = kops.verify_topk(
-                        jnp.asarray(self._data_blocks[p]), qj, cand, k_l,
-                        force=self.force)
-                    d2s.append(d2l)
-                    gids.append(jnp.where(locl >= 0, locl + p * nl, -1))
-            with sp("shard.merge",
-                    **roofline.shard_merge_cost(P_, B, k_l).attrs()):
-                d2_pool = jnp.concatenate(d2s, axis=1)
-                gid_pool = jnp.concatenate(gids, axis=1)
-                ids, dd = _merge_topk(d2_pool, gid_pool, k)
-                ids, dd = otrace.block(ids, dd)
+        # estimate
+        d2ps = [_estimate_block(jnp.asarray(self._proj_blocks[p]),
+                                qp, p * nl, self.n)
+                for p in range(P_)]
+        # select: the counts-only bisection to one global threshold
+        row_max = [jnp.max(jnp.where(jnp.isfinite(d), d, 0.0), axis=1)
+                   for d in d2ps]
+        hi0 = row_max[0]
+        for r in row_max[1:]:
+            hi0 = jnp.maximum(hi0, r)  # pmax
+        hi = jax.lax.bitcast_convert_type(hi0, jnp.int32)
+        lo = jnp.full_like(hi, -1)
+        for _ in range(BISECT_ROUNDS):
+            mid = _bisect_mid(lo, hi)
+            cnt = _count_le_bits(d2ps[0], mid)
+            for d in d2ps[1:]:
+                cnt = cnt + _count_le_bits(d, mid)  # psum
+            lo, hi = _bisect_step(lo, hi, cnt, T)
+        tau = jax.lax.bitcast_convert_type(hi, jnp.float32)
+        cands, cnts = [], []
+        for p in range(P_):
+            cand, cnt_loc = _compact_block(d2ps[p], tau, cap)
+            cands.append(cand)
+            cnts.append(cnt_loc)
+        counts = jnp.stack(cnts)  # (P, B) — the counts all-gather
+        # verify, shard by shard
+        d2s, gids = [], []
+        for p in range(P_):
+            cand = cands[p]
+            if pq:
+                lut = self.codecs[p].lookup_tables(qj)
+                codes = jnp.asarray(self._codes_blocks[p])[
+                    jnp.maximum(cand, 0)]
+                adc = kops.adc_dist(codes, lut, force=self.force)
+                adc = jnp.where(cand < 0, jnp.inf, adc)
+                _, rsel = jax.lax.top_k(-adc, R_l)
+                cand = jnp.take_along_axis(cand, rsel, axis=1)
+            d2l, locl = kops.verify_topk(
+                jnp.asarray(self._data_blocks[p]), qj, cand, k_l,
+                force=self.force)
+            d2s.append(d2l)
+            gids.append(jnp.where(locl >= 0, locl + p * nl, -1))
+        # merge
+        d2_pool = jnp.concatenate(d2s, axis=1)
+        gid_pool = jnp.concatenate(gids, axis=1)
+        ids, dd = _merge_topk(d2_pool, gid_pool, k)
         return ids, dd, counts
-
-    def query_traced(self, q: np.ndarray, k: int, T: int):
-        """Stage-by-stage eager twin with ``shard.*`` spans — identical
-        answers to :meth:`query` (exact collectives, same stage math),
-        run over the host block layout like ``fused_ann_query_traced``."""
-        q = np.atleast_2d(np.asarray(q, np.float32))
-        qp = jnp.asarray(self.family.project(q))
-        ids, dd, counts = self._query_emulated(jnp.asarray(q), qp, k=k, T=T,
-                                               traced=True)
-        return (np.asarray(ids, np.int32), np.asarray(dd, np.float32),
-                np.asarray(counts, np.int64))
 
     # -- CP ---------------------------------------------------------------
 
-    def cp_query(self, k: int, *, thresh2: float, traced: bool = False):
+    def cp_query(self, k: int, *, thresh2: float):
         """(c,k)-ACP via the sharded ring join.  Returns (pairs (k',2)
         int32 original ids i<j ascending by exact distance, distances
         (k',) float32, pair_counts (P,) int64, tiles_pruned int)."""
@@ -680,9 +652,9 @@ class ShardedFlatIndex:
                     np.zeros((self.P,), np.int64), 0)
         self._build_cp_layout()
         kj = cp_join_budget(kk, self.n * (self.n - 1) // 2)
-        if self.emulated or traced:
+        if self.emulated:
             fd, fi, fj, pair_counts, tp = self._cp_emulated(
-                kj, thresh2=thresh2, traced=traced)
+                kj, thresh2=thresh2)
         else:
             with self.mesh:
                 fd, fi, fj, pair_counts, tp = _cp_program(
@@ -708,60 +680,49 @@ class ShardedFlatIndex:
         return (pairs[resort], dists[resort],
                 np.asarray(pair_counts, np.int64), int(tp))
 
-    def _cp_emulated(self, k: int, *, thresh2: float, traced: bool):
-        from repro.obs import roofline
-
-        tr = otrace.get_tracer() if traced else None
-        sp = tr.span if tr is not None else otrace.span
+    def _cp_emulated(self, k: int, *, thresh2: float):
         P_, nl, tile = self.P, self.cp_nl, self.cp_tile_eff
         blocks = [(jnp.asarray(self._cp_data_blocks[p]),
                    jnp.asarray(self._cp_key_blocks[p]),
                    jnp.arange(p * nl, (p + 1) * nl)) for p in range(P_)]
         norms = [jnp.sum(b[0] * b[0], axis=-1) for b in blocks]
 
-        with sp("shard.cp", P=P_, n=self.n, k=k):
-            best = []
-            pv_cnt = [jnp.int32(0)] * P_
-            tp_cnt = jnp.int32(0)
-            with sp("shard.verify", round=0):
-                for p in range(P_):
-                    pts, key, sgid = blocks[p]
-                    d, i_, j_, pv, tp = _join_block(
-                        pts, norms[p], key, sgid, pts, norms[p], key, sgid,
-                        jnp.float32(jnp.inf), k=k, n_valid=self.n,
-                        thresh2=thresh2, tile=tile)
-                    best.append((d, i_, j_))
-                    pv_cnt[p] = pv_cnt[p] + pv
-                    tp_cnt = tp_cnt + tp
+        best = []
+        pv_cnt = [jnp.int32(0)] * P_
+        tp_cnt = jnp.int32(0)
+        for p in range(P_):  # round 0: each shard's self-join
+            pts, key, sgid = blocks[p]
+            d, i_, j_, pv, tp = _join_block(
+                pts, norms[p], key, sgid, pts, norms[p], key, sgid,
+                jnp.float32(jnp.inf), k=k, n_valid=self.n,
+                thresh2=thresh2, tile=tile)
+            best.append((d, i_, j_))
+            pv_cnt[p] = pv_cnt[p] + pv
+            tp_cnt = tp_cnt + tp
+        ub2 = _global_ub2(jnp.concatenate([b[0] for b in best]), k)
+        recv = list(range(P_))  # recv[p]: which block shard p holds
+        for r in range(1, P_):
+            recv = [recv[(p - 1) % P_] for p in range(P_)]  # the ring hop
+            for p in range(P_):
+                pts, key, sgid = blocks[p]
+                rp, rk, rs = blocks[recv[p]]
+                d, i_, j_, pv, tp = _join_block(
+                    pts, norms[p], key, sgid, rp, norms[recv[p]], rk,
+                    rs, ub2, k=k, n_valid=self.n, thresh2=thresh2,
+                    tile=tile)
+                cat_d = jnp.concatenate([best[p][0], d])
+                cat_i = jnp.concatenate([best[p][1], i_])
+                cat_j = jnp.concatenate([best[p][2], j_])
+                neg, sel = jax.lax.top_k(-cat_d, k)
+                best[p] = (-neg, cat_i[sel], cat_j[sel])
+                pv_cnt[p] = pv_cnt[p] + pv
+                tp_cnt = tp_cnt + tp
             ub2 = _global_ub2(jnp.concatenate([b[0] for b in best]), k)
-            recv = list(range(P_))  # recv[p]: which block shard p holds
-            for r in range(1, P_):
-                with sp("shard.exchange", round=r,
-                        **roofline.shard_ring_cost(
-                            P_, nl, self.d, k).attrs()):
-                    recv = [recv[(p - 1) % P_] for p in range(P_)]
-                with sp("shard.verify", round=r):
-                    for p in range(P_):
-                        pts, key, sgid = blocks[p]
-                        rp, rk, rs = blocks[recv[p]]
-                        d, i_, j_, pv, tp = _join_block(
-                            pts, norms[p], key, sgid, rp, norms[recv[p]], rk,
-                            rs, ub2, k=k, n_valid=self.n, thresh2=thresh2,
-                            tile=tile)
-                        cat_d = jnp.concatenate([best[p][0], d])
-                        cat_i = jnp.concatenate([best[p][1], i_])
-                        cat_j = jnp.concatenate([best[p][2], j_])
-                        neg, sel = jax.lax.top_k(-cat_d, k)
-                        best[p] = (-neg, cat_i[sel], cat_j[sel])
-                        pv_cnt[p] = pv_cnt[p] + pv
-                        tp_cnt = tp_cnt + tp
-                ub2 = _global_ub2(jnp.concatenate([b[0] for b in best]), k)
-            with sp("shard.merge",
-                    **roofline.shard_merge_cost(P_, 1, k).attrs()):
-                all_d = jnp.concatenate([b[0] for b in best])
-                all_i = jnp.concatenate([b[1] for b in best])
-                all_j = jnp.concatenate([b[2] for b in best])
-                neg, sel = jax.lax.top_k(-all_d, k)
-                fd, fi, fj = otrace.block(-neg, all_i[sel], all_j[sel])
+        # merge
+        all_d = jnp.concatenate([b[0] for b in best])
+        all_i = jnp.concatenate([b[1] for b in best])
+        all_j = jnp.concatenate([b[2] for b in best])
+        neg, sel = jax.lax.top_k(-all_d, k)
+        fd, fi, fj = -neg, all_i[sel], all_j[sel]
         pair_counts = jnp.stack(pv_cnt)
         return fd, fi, fj, pair_counts, int(tp_cnt)

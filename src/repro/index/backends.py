@@ -104,8 +104,11 @@ class BaseIndex:
         n_bad = int(bad_rows.sum())
         if n_bad:
             q = np.where(bad_rows[:, None], np.float32(0.0), q)
-        with otrace.span("index.search", backend=self.backend_name,
-                         B=int(q.shape[0]), k=k) as sp:
+        with otrace.span("index.search") as sp:
+            if sp is not None:
+                sp.attrs.update(call=otrace.new_call_id(),
+                                backend=self.backend_name,
+                                B=int(q.shape[0]), k=k)
             res = self._search(q, min(k, self.n))
             if n_bad:
                 # np.where builds fresh arrays — backends may hand back
@@ -136,8 +139,10 @@ class BaseIndex:
             raise NotImplementedError(
                 f"backend {self.backend_name!r} does not support closest-pair"
             )
-        with otrace.span("index.cp_search", backend=self.backend_name,
-                         k=int(k)) as sp:
+        with otrace.span("index.cp_search") as sp:
+            if sp is not None:
+                sp.attrs.update(call=otrace.new_call_id(),
+                                backend=self.backend_name, k=int(k))
             res = self._cp_search(int(k))
             if sp is not None:
                 sp.attrs["work"] = res.stats.as_dict()
@@ -305,57 +310,46 @@ class FlatBackend(BaseIndex):
                  else self.n >= 8192) and k <= 128
         force = (self.force if self.force is not None
                  else (None if self.use_kernels else "ref"))
-        traced = otrace.enabled()
         if self.codec is None:
-            if traced and fused:
-                # stage-by-stage eager twin: same math, per-stage spans
-                from repro.core.fused import fused_ann_query_traced
-
-                ids, dd, cnt = fused_ann_query_traced(
-                    self.impl, q, k=k, T=T, force=force, with_count=True)
-            elif traced:
-                # the unfused pipeline stays one jit call: a single
-                # span bounds it, including host materialization
-                with otrace.span("ann.query", B=B, n=self.n, k=k, T=T,
-                                 fused=False):
-                    ids, dd, cnt = otrace.block(ann_query(
-                        self.impl, q, k=k, T=T,
-                        use_kernels=self.use_kernels, fused=False,
-                        force=force, with_count=True))
-                    ids, dd = np.asarray(ids), np.asarray(dd)
-            else:
+            with otrace.span("ann.dispatch"):
                 ids, dd, cnt = ann_query(self.impl, q, k=k, T=T,
                                          use_kernels=self.use_kernels,
                                          fused=fused, force=force,
                                          with_count=True)
+            with otrace.span("ann.wait"):
+                ids = np.asarray(ids)
             # canonical answer floats (shared with sharded-flat) — the
             # in-pipeline d² only ranked the candidates
-            ids = np.asarray(ids)
-            dd = np.asarray(answer_distances(self.impl.data, ids, q))
+            with otrace.span("ann.answer"):
+                dd = np.asarray(answer_distances(self.impl.data, ids, q))
+            with otrace.span("ann.counts"):
+                selected = self._record_select(cnt, T)
             return SearchResult(
                 ids, dd,
                 stats=WorkStats(rounds=B, candidates_verified=B * T,
-                                candidates_selected=self._record_select(
-                                    cnt, T)),
+                                candidates_selected=selected),
             )
         from repro.quant import quant_ann_query
-        from repro.quant.search import quant_ann_query_traced
 
         rerank = (self.rerank if self.rerank is not None
                   else max(4 * k, T // 3, 64))
         R = min(max(rerank, k), T)
-        query_fn = quant_ann_query_traced if traced else quant_ann_query
-        ids, dd, cnt = query_fn(
-            self.impl, self.codec, self.codes, q, k=k, T=T, R=R,
-            store_raw=self.store_raw, force=force, fused=fused,
-            with_count=True,
-        )
+        with otrace.span("ann.dispatch"):
+            ids, dd, cnt = quant_ann_query(
+                self.impl, self.codec, self.codes, q, k=k, T=T, R=R,
+                store_raw=self.store_raw, force=force, fused=fused,
+                with_count=True,
+            )
+        with otrace.span("ann.wait"):
+            ids, dd = np.asarray(ids), np.asarray(dd)
+        with otrace.span("ann.counts"):
+            selected = self._record_select(cnt, T)
         return SearchResult(
-            np.asarray(ids), np.asarray(dd),
+            ids, dd,
             stats=WorkStats(
                 rounds=B,
                 candidates_verified=B * R if self.store_raw else 0,
-                candidates_selected=self._record_select(cnt, T),
+                candidates_selected=selected,
                 point_distance_computations=B * T,  # the ADC rerank tier
             ),
         )
@@ -367,10 +361,12 @@ class FlatBackend(BaseIndex):
         gamma = float(cfg.options.get("cp_gamma", 1.0))
         force = (self.force if self.force is not None
                  else (None if self.use_kernels else "ref"))
-        key = np.asarray(self.impl.projected)[:, 0]
         if self.codec is None:
-            r = cp_fused_search(np.asarray(self.impl.data), k, m=cfg.m,
-                                c=cfg.cp_c, gamma=gamma, force=force, key=key)
+            with otrace.span("cp.d2h"):
+                data = np.asarray(self.impl.data)
+                key = np.asarray(self.impl.projected)[:, 0]
+            r = cp_fused_search(data, k, m=cfg.m, c=cfg.cp_c, gamma=gamma,
+                                force=force, key=key)
             return CpSearchResult(
                 r.pairs, r.distances,
                 stats=WorkStats(candidates_verified=r.pairs_verified,
@@ -379,6 +375,7 @@ class FlatBackend(BaseIndex):
             )
         from repro.quant import quant_cp_search
 
+        key = np.asarray(self.impl.projected)[:, 0]
         if self.store_raw and getattr(self, "_cp_recon", None) is None:
             # codes are immutable: decode once and reuse across queries.
             # Codes-only indexes keep the per-call decode instead — they
@@ -525,13 +522,12 @@ class ShardedFlatBackend(BaseIndex):
     def _search(self, q: np.ndarray, k: int) -> SearchResult:
         T = candidate_budget(self.params, self.n, k)
         B = q.shape[0]
-        if otrace.enabled():
-            ids, dd, counts = self.impl.query_traced(q, k, T)
-        else:
+        with otrace.span("ann.dispatch"):
             ids, dd, counts = self.impl.query(q, k, T)
         # canonical answer floats — same shared program as ``flat``, so
         # id-parity implies bit-identical distances (DESIGN.md §15)
-        dd = np.asarray(answer_distances(self._data_jnp, ids, q))
+        with otrace.span("ann.answer"):
+            dd = np.asarray(answer_distances(self._data_jnp, ids, q))
         per_shard = counts.sum(axis=1)  # (P,) survivor totals
         selected = int(per_shard.sum())
         stats = WorkStats(
@@ -559,7 +555,7 @@ class ShardedFlatBackend(BaseIndex):
         thresh2 = (np.inf if not np.isfinite(gamma)
                    else cp_threshold2(cfg.cp_c, cfg.m, gamma))
         pairs, dd, pair_counts, pruned = self.impl.cp_query(
-            k, thresh2=float(thresh2), traced=otrace.enabled())
+            k, thresh2=float(thresh2))
         verified = int(pair_counts.sum())
         return CpSearchResult(
             pairs, dd,

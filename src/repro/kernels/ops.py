@@ -10,22 +10,15 @@ op-by-op — bit-accurate, slow).  Each wrapper therefore routes:
 
 `force` overrides: "pallas" | "interpret" | "ref" | None (auto).
 
-Observability: while ``repro.obs`` tracing is enabled, every dispatch
-executed EAGERLY (concrete arguments — i.e. not under an enclosing
-jit trace, where wall time is meaningless) records a ``kernel.<op>``
-span carrying the op's modeled bytes/FLOPs (``repro.obs.roofline``)
-and closes only after ``block_until_ready``, so traces place each
-kernel on the roofline.  Disabled cost is one boolean check per call.
+Observability: the main-path kernels carry stable names
+(``pallas_call(name=...)``), which the profiler shows as their op
+names; the stages around them are ``jax.named_scope``s of the calling
+program (DESIGN.md §12).  Nothing here times or syncs anything.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-
-from repro.obs import roofline as _roofline
-from repro.obs import trace as _otrace
 
 from . import ref
 from .adc import adc_dist_pallas
@@ -46,76 +39,6 @@ def _mode(force: str | None) -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
-def _instrumented(name: str, cost_of):
-    """Wrap a dispatch in a roofline-annotated kernel span.
-
-    ``cost_of(*args, **kw)`` returns the op's :class:`KernelCost` for
-    the call's shapes.  Instrumentation engages only when tracing is
-    on AND every argument is concrete (an abstract jax tracer means an
-    enclosing jit is tracing this call — timing it would measure trace
-    construction, not execution); the span closes after
-    ``block_until_ready`` so device time lands inside it.
-    """
-
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*args, **kw):
-            if not _otrace.enabled() or not _otrace.concrete(*args):
-                return fn(*args, **kw)
-            try:
-                attrs = cost_of(*args, **kw).attrs()
-            except Exception:  # shape we did not model: still time it
-                attrs = {}
-            with _otrace.get_tracer().span(name, **attrs):
-                out = fn(*args, **kw)
-                _otrace.block(out)
-            return out
-
-        return wrapper
-
-    return deco
-
-
-def _pairwise_cost(q, x, **kw) -> _roofline.KernelCost:
-    if x.ndim == 3:
-        B, N, d = x.shape
-    else:
-        (B, d), N = q.shape, x.shape[0]
-    return _roofline.pairwise_sq_dist_cost(B, N, d)
-
-
-def _project_cost(x, a, qp, **kw) -> _roofline.KernelCost:
-    return _roofline.project_dist_cost(x.shape[0], x.shape[1], a.shape[1],
-                                       qp.shape[0])
-
-
-def _adc_cost(codes, lut, **kw) -> _roofline.KernelCost:
-    B, S, V = lut.shape
-    return _roofline.adc_dist_cost(B, codes.shape[-2], S, V)
-
-
-def _topk_cost(d, k, **kw) -> _roofline.KernelCost:
-    return _roofline.topk_cost(d.shape[0], d.shape[1], k)
-
-
-def _select_cost(d, T, *, T_pad=None, **kw) -> _roofline.KernelCost:
-    B, N = d.shape
-    if T_pad is None:
-        T_pad = T + max(256, T // 8)
-    return _roofline.radius_select_cost(B, N, min(max(T_pad, T), N))
-
-
-def _verify_cost(data, q, cand, k, **kw) -> _roofline.KernelCost:
-    B, Tc = cand.shape
-    return _roofline.verify_topk_cost(B, Tc, data.shape[1], k)
-
-
-def _pair_join_cost(x, key, k, *, block_n=128, **kw) -> _roofline.KernelCost:
-    return _roofline.pair_join_cost(x.shape[0], x.shape[1], k,
-                                    block_n=block_n)
-
-
-@_instrumented("kernel.pairwise_sq_dist", _pairwise_cost)
 def pairwise_sq_dist(q: jax.Array, x: jax.Array, *, force: str | None = None,
                      **block_kw) -> jax.Array:
     """(B,d) × (N,d) → (B,N) squared Euclidean distances (f32).
@@ -135,7 +58,6 @@ def pairwise_sq_dist(q: jax.Array, x: jax.Array, *, force: str | None = None,
     return pairwise_sq_dist_pallas(q, x, interpret=interpret, **block_kw)
 
 
-@_instrumented("kernel.project_dist", _project_cost)
 def project_dist(x: jax.Array, a: jax.Array, qp: jax.Array, *,
                  force: str | None = None, **block_kw) -> jax.Array:
     """Fused (x@a) projected distances to qp: (N,d),(d,m),(B,m) → (B,N)."""
@@ -145,7 +67,6 @@ def project_dist(x: jax.Array, a: jax.Array, qp: jax.Array, *,
     return project_dist_pallas(x, a, qp, interpret=(mode == "interpret"), **block_kw)
 
 
-@_instrumented("kernel.adc_dist", _adc_cost)
 def adc_dist(codes: jax.Array, lut: jax.Array, *, force: str | None = None,
              **block_kw) -> jax.Array:
     """Asymmetric distances: codes (N,S) or (B,N,S) × LUTs (B,S,V) → (B,N).
@@ -165,7 +86,6 @@ def adc_dist(codes: jax.Array, lut: jax.Array, *, force: str | None = None,
     return adc_dist_pallas(codes, lut, interpret=interpret, **block_kw)
 
 
-@_instrumented("kernel.topk_smallest", _topk_cost)
 def topk_smallest(d: jax.Array, k: int, *, force: str | None = None,
                   **block_kw) -> tuple[jax.Array, jax.Array]:
     """Row-wise k smallest (values, indices), ascending.
@@ -191,7 +111,6 @@ def default_select_seed(d: jax.Array, T: int, *, stride: int = 8) -> jax.Array:
     return jnp.mean(samp, axis=1) * jnp.float32(max(T / N, 1e-3))
 
 
-@_instrumented("kernel.radius_select", _select_cost)
 def radius_select(d: jax.Array, T: int, *, tau0: jax.Array | None = None,
                   T_pad: int | None = None, force: str | None = None,
                   with_count: bool = False, **block_kw):
@@ -240,10 +159,11 @@ def radius_select(d: jax.Array, T: int, *, tau0: jax.Array | None = None,
     # buffer overflow (pathological tie cluster at the threshold) drops
     # survivors in index order — arbitrarily wrong ones — so reroute to
     # the exact sort rather than return a degraded candidate set
-    vals, idx, cnt_out = jax.lax.cond(
-        jnp.any(cnt > T_pad),
-        lambda: ref.topk_smallest(d, T) + (jnp.full((B,), T, jnp.int32),),
-        _trim)
+    with jax.named_scope("finish"):
+        vals, idx, cnt_out = jax.lax.cond(
+            jnp.any(cnt > T_pad),
+            lambda: ref.topk_smallest(d, T) + (jnp.full((B,), T, jnp.int32),),
+            _trim)
     if with_count:
         return vals, idx, cnt_out
     return vals, idx
@@ -262,39 +182,19 @@ def pair_join(x, key, k: int, *, thresh2: float, force: str | None = None,
     k > 128 is outside the in-VMEM selection network's regime and
     routes through the host oracle on every dispatch mode.
     """
-    mode = _mode(force)
-
-    def dispatch():
-        if mode == "ref" or k > 128:
-            return ref.pair_join(x, key, k, thresh2=thresh2, block_n=block_n)
-        return pair_join_pallas(x, key, k, thresh2=float(thresh2),
-                                block_n=block_n,
-                                interpret=(mode == "interpret"))
-
-    if not _otrace.enabled() or not _otrace.concrete(x, key):
-        return dispatch()
-    # unlike the other ops the join's traffic is data-dependent (the
-    # γ·t·ub filter skips tiles), so the span's model is refined
-    # post-hoc from the kernel's own tiles_pruned counter
-    cost = _pair_join_cost(x, key, k, block_n=block_n)
-    with _otrace.get_tracer().span("kernel.pair_join", **cost.attrs()) as sp:
-        out = dispatch()
-        _otrace.block(out)
-        if sp is not None:
-            import numpy as _np
-
-            n_ti = max(-(-x.shape[0] // block_n), 1)
-            pruned = int(_np.asarray(out[3])[1])
-            visited = n_ti * (n_ti + 1) // 2 - pruned
-            realized = _roofline.pair_join_cost(
-                x.shape[0], x.shape[1], k, block_n=block_n,
-                tiles_visited=visited)
-            sp.attrs.update(realized.attrs())
-            sp.attrs["tiles_pruned"] = pruned
-    return out
+    if not pair_join_on_device(k, force):
+        return ref.pair_join(x, key, k, thresh2=thresh2, block_n=block_n)
+    return pair_join_pallas(x, key, k, thresh2=float(thresh2),
+                            block_n=block_n,
+                            interpret=(_mode(force) == "interpret"))
 
 
-@_instrumented("kernel.verify_topk", _verify_cost)
+def pair_join_on_device(k: int, force: str | None = None) -> bool:
+    """Whether :func:`pair_join` runs the join kernel, which reads its
+    rows from the device (the host oracle takes k > 128 and "ref")."""
+    return _mode(force) != "ref" and k <= 128
+
+
 def verify_topk(data: jax.Array, q: jax.Array, cand: jax.Array, k: int, *,
                 force: str | None = None, **block_kw
                 ) -> tuple[jax.Array, jax.Array]:

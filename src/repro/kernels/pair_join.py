@@ -197,6 +197,8 @@ def pair_join_pallas(
 
 @functools.partial(
     jax.jit, static_argnames=("k", "thresh2", "block_n", "interpret"))
+@jax.named_scope("cp")
+@jax.named_scope("join")
 def _pair_join_jit(
     x: jax.Array,
     key: jax.Array,
@@ -258,6 +260,7 @@ def _pair_join_jit(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
+        name="_pair_join_jit",
     )(key_lo, key_hi, xp)
     return vals[0], pi[0], pj[0], stats[0, :3]
 

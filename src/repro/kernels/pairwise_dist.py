@@ -84,6 +84,7 @@ def pairwise_sq_dist_pallas(
         out_specs=pl.BlockSpec((bB, bN), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Np), jnp.float32),
         interpret=interpret,
+        name="pairwise_sq_dist_pallas",
     )(qp, xp)
     return out[:B, :N]
 

@@ -223,11 +223,6 @@ def radius_select_pallas(
     bN = min(block_n, _ceil_mult(N, 128))
     Bh = _ceil_mult(B, 8)
     Np = _ceil_mult(N, bN)
-    dp = jnp.full((Bh, Np), jnp.inf, jnp.float32).at[:B, :N].set(d)
-    t0 = jnp.zeros((Bh, 128), jnp.float32).at[:B, :].set(
-        jnp.broadcast_to(
-            jnp.maximum(jnp.asarray(tau0, jnp.float32), 1e-30)[:, None],
-            (B, 128)))
     n_tiles = Np // bN
     P = iters + 2
     kern = functools.partial(
@@ -237,30 +232,38 @@ def radius_select_pallas(
     # output block index stays put, so nothing is written back early
     out_tile = pl.BlockSpec(
         (Bh, bN), lambda p, j: (0, jnp.where(p == P - 1, j, 0)))
-    vals_t, idx_t = pl.pallas_call(
-        kern,
-        grid=(P, n_tiles),
-        in_specs=[
-            pl.BlockSpec((Bh, 128), lambda p, j: (0, 0)),
-            pl.BlockSpec((Bh, bN), lambda p, j: (0, j)),
-        ],
-        out_specs=[out_tile, out_tile],
-        out_shape=[
-            jax.ShapeDtypeStruct((Bh, Np), jnp.float32),
-            jax.ShapeDtypeStruct((Bh, Np), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((Bh, 128), jnp.float32),  # bisection count
-            pltpu.VMEM((Bh, 128), jnp.float32),  # ladder counts (lane l)
-            pltpu.VMEM((Bh, 128), jnp.float32),  # bracket lo
-            pltpu.VMEM((Bh, 128), jnp.float32),  # bracket hi
-            pltpu.VMEM((Bh, 128), jnp.float32),  # running data max
-        ],
-        interpret=interpret,
-    )(t0, dp)
+    with jax.named_scope("radius"):
+        dp = jnp.full((Bh, Np), jnp.inf, jnp.float32).at[:B, :N].set(d)
+        t0 = jnp.zeros((Bh, 128), jnp.float32).at[:B, :].set(
+            jnp.broadcast_to(
+                jnp.maximum(jnp.asarray(tau0, jnp.float32), 1e-30)[:, None],
+                (B, 128)))
+        vals_t, idx_t = pl.pallas_call(
+            kern,
+            grid=(P, n_tiles),
+            in_specs=[
+                pl.BlockSpec((Bh, 128), lambda p, j: (0, 0)),
+                pl.BlockSpec((Bh, bN), lambda p, j: (0, j)),
+            ],
+            out_specs=[out_tile, out_tile],
+            out_shape=[
+                jax.ShapeDtypeStruct((Bh, Np), jnp.float32),
+                jax.ShapeDtypeStruct((Bh, Np), jnp.int32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((Bh, 128), jnp.float32),  # bisection count
+                pltpu.VMEM((Bh, 128), jnp.float32),  # ladder counts (lane l)
+                pltpu.VMEM((Bh, 128), jnp.float32),  # bracket lo
+                pltpu.VMEM((Bh, 128), jnp.float32),  # bracket hi
+                pltpu.VMEM((Bh, 128), jnp.float32),  # running data max
+            ],
+            interpret=interpret,
+            name="radius_select_pallas",
+        )(t0, dp)
     return _concat_tiles(vals_t[:B], idx_t[:B], bN, T_pad)
 
 
+@jax.named_scope("compact")
 def _concat_tiles(vals_t, idx_t, bN: int, T_pad: int):
     """Join each row's front-packed tile runs into its first T_pad slots.
 

@@ -136,32 +136,35 @@ def verify_topk_pallas(
     # (n, 1, d): one point per leading index, so a single-row copy is a
     # whole tile of the HBM layout.  Not a bitcast: XLA relays the data
     # out from 8-row to 1-row tiles, a copy of the whole array per call
-    rows3 = jnp.asarray(data, jnp.float32).reshape(n, 1, d)
+    with jax.named_scope("relayout"):
+        rows3 = jnp.asarray(data, jnp.float32).reshape(n, 1, d)
     kern = functools.partial(verify_topk_kernel, block_t=bT, rows=R)
-    vals, idx = pl.pallas_call(
-        kern,
-        grid=(Bp // R, Tp // bT),
-        in_specs=[
-            pl.BlockSpec((R, d), lambda b, j: (b, 0)),
-            pl.BlockSpec((R, bT), lambda b, j: (b, j),
-                         memory_space=pltpu.SMEM),  # ids for the copies
-            pl.BlockSpec((R, bT), lambda b, j: (b, j)),  # ids as a vector
-            pl.BlockSpec(memory_space=pl.ANY),  # data stays in HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((R, k), lambda b, j: (b, 0)),
-            pl.BlockSpec((R, k), lambda b, j: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Bp, k), jnp.float32),
-            jax.ShapeDtypeStruct((Bp, k), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((R * bT, 1, d), jnp.float32),  # gathered rows
-            pltpu.SemaphoreType.DMA((R,)),
-        ],
-        interpret=interpret,
-    )(qp, cp, cp, rows3)
+    with jax.named_scope("kernel"):
+        vals, idx = pl.pallas_call(
+            kern,
+            grid=(Bp // R, Tp // bT),
+            in_specs=[
+                pl.BlockSpec((R, d), lambda b, j: (b, 0)),
+                pl.BlockSpec((R, bT), lambda b, j: (b, j),
+                             memory_space=pltpu.SMEM),  # ids for the copies
+                pl.BlockSpec((R, bT), lambda b, j: (b, j)),  # ids as a vector
+                pl.BlockSpec(memory_space=pl.ANY),  # data stays in HBM
+            ],
+            out_specs=[
+                pl.BlockSpec((R, k), lambda b, j: (b, 0)),
+                pl.BlockSpec((R, k), lambda b, j: (b, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((Bp, k), jnp.float32),
+                jax.ShapeDtypeStruct((Bp, k), jnp.int32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((R * bT, 1, d), jnp.float32),  # gathered rows
+                pltpu.SemaphoreType.DMA((R,)),
+            ],
+            interpret=interpret,
+            name="verify_topk_pallas",
+        )(qp, cp, cp, rows3)
     vals, idx = vals[:B], idx[:B]
     return vals, jnp.where(jnp.isinf(vals), -1, idx)
 

@@ -1,9 +1,10 @@
 """repro.obs — tracing, rooflines, metrics, and quality auditing.
 
-The observability layer (DESIGN.md §12–§13): hierarchical wall-clock
-spans over every query engine (``obs.trace``), modeled bytes/FLOPs per
-kernel dispatch with achieved-arithmetic-intensity placement
-(``obs.roofline``), Chrome-trace/Perfetto + flat-summary exporters
+The observability layer (DESIGN.md §12–§13): hierarchical host spans
+over every query engine on the profiler's clock (``obs.trace``; they
+record inside ``obs.tracing()`` and during a ``jax.profiler`` session),
+device peaks and modeled bytes/FLOPs with achieved-arithmetic-intensity
+placement (``obs.roofline``), Chrome-trace/Perfetto + flat-summary exporters
 (``obs.export``), a process-global metrics registry with Prometheus
 text exposition (``obs.metrics``), a shadow ground-truth quality
 auditor — online recall@k / approximation ratio / Lemma-3 CI coverage
@@ -27,6 +28,8 @@ Quickstart::
     print(auditor.report())                 # recall / ratio / coverage
     print(obs.get_registry().to_prometheus())
 """
+import jax.monitoring
+
 from . import drift, export, metrics, quality, roofline, trace
 from .drift import DriftMonitor, DriftReport
 from .export import (coverage, save_chrome_trace, stage_summary,
@@ -35,14 +38,14 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       get_registry)
 from .quality import QualityAuditor, QualityReport
 from .roofline import DevicePeaks, KernelCost, achieved, device_kind
-from .trace import (Span, Trace, Tracer, add_span, block, concrete,
-                    disable, enable, enabled, get_tracer, span)
+from .trace import (Span, Trace, Tracer, add_span, disable, enable,
+                    enabled, get_tracer, recording, span)
 from .trace import trace as tracing
 
 __all__ = [
     "tracing", "Span", "Trace", "Tracer", "get_tracer",
-    "enabled", "enable", "disable", "span", "add_span", "block",
-    "concrete", "export", "roofline", "trace", "KernelCost",
+    "enabled", "enable", "disable", "recording", "span", "add_span",
+    "export", "roofline", "trace", "KernelCost",
     "DevicePeaks", "achieved", "device_kind", "to_chrome_trace",
     "save_chrome_trace", "validate_chrome_trace", "stage_summary",
     "coverage", "metrics", "Counter", "Gauge", "Histogram",
@@ -55,3 +58,9 @@ __all__ = [
 get_registry().gauge(
     "trace_dropped_spans", "spans dropped by the tracer ring buffer"
 ).set_fn(lambda: float(get_tracer().dropped))
+
+
+# one jax.monitoring listener for the process: every backend compile
+# counts in ``jit_compiles_total`` and, while spans record, leaves a
+# ``jit.compile`` span where it happened
+jax.monitoring.register_event_duration_secs_listener(trace.record_compile)

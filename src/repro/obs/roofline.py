@@ -1,11 +1,12 @@
-"""Roofline models: modeled bytes + FLOPs per kernel dispatch.
+"""Roofline models: modeled bytes + FLOPs per kernel call.
 
 Extends ``benchmarks/cost_model.query_traffic_model``'s per-stage HBM
-byte accounting down to the individual ``repro.kernels.ops`` dispatch:
-every kernel span records the bytes the op must move and the FLOPs it
-must execute for its argument shapes, so a trace pairs each measured
-duration with its model and answers *memory-bound or compute-bound,
-and at what fraction of peak* (DESIGN.md §12).
+byte accounting down to the individual kernel: the bytes an op must
+move and the FLOPs it must execute for its argument shapes, so a
+measured time (``benchmarks/kernel_micro.py``, ``sharded_scale.py``, or
+a span carrying ``bytes``/``flops`` attrs in ``obs.export``) answers
+*memory-bound or compute-bound, and at what fraction of peak*
+(DESIGN.md §12).
 
 Conventions:
 
@@ -31,8 +32,9 @@ import dataclasses
 
 __all__ = ["KernelCost", "DevicePeaks", "PEAKS", "device_kind", "get_peaks",
            "set_peaks", "pairwise_sq_dist_cost", "project_dist_cost",
-           "adc_dist_cost", "topk_cost", "radius_select_cost",
-           "verify_topk_cost", "pair_join_cost", "achieved"]
+           "topk_cost", "radius_select_cost", "verify_topk_cost",
+           "shard_exchange_cost", "shard_merge_cost", "shard_ring_cost",
+           "achieved"]
 
 F32 = 4
 
@@ -50,7 +52,7 @@ class KernelCost:
         return self.flops / max(self.bytes, 1)
 
     def attrs(self) -> dict:
-        """The span-attribute form kernel instrumentation records."""
+        """The span-attribute form ``obs.export`` places on the roofline."""
         return {"bytes": int(self.bytes), "flops": int(self.flops),
                 "intensity": round(self.intensity, 4)}
 
@@ -128,15 +130,6 @@ def project_dist_cost(N: int, d: int, m: int, B: int) -> KernelCost:
                       flops=proj.flops + dist.flops)
 
 
-def adc_dist_cost(B: int, N: int, S: int, V: int,
-                  code_bytes: int = 1) -> KernelCost:
-    """ADC rerank: codes (N,S) or (B,N,S) at 1 byte/slot + LUTs
-    (B,S,V) f32 read once; one gather+add per (b, n, s)."""
-    return KernelCost(bytes=B * N * S * code_bytes + B * S * V * F32
-                      + B * N * F32,
-                      flops=2 * B * N * S)
-
-
 def topk_cost(B: int, N: int, k: int) -> KernelCost:
     """Selection-network top-k: one read of (B,N); ~N·k compares/row."""
     return KernelCost(bytes=(B * N + 2 * B * k) * F32, flops=B * N * k)
@@ -158,21 +151,6 @@ def verify_topk_cost(B: int, Tc: int, d: int, k: int) -> KernelCost:
     distances are 2·B·Tc·d FLOPs plus the streaming top-k compares."""
     return KernelCost(bytes=(B * Tc * d + B * d + 4 * B * k) * F32,
                       flops=2 * B * Tc * d + B * Tc * k)
-
-
-def pair_join_cost(n: int, d: int, k: int, block_n: int = 128,
-                   tiles_visited: int | None = None) -> KernelCost:
-    """CP JOIN: band-major sweep over the upper-triangular tile space.
-    Each *visited* tile DMAs two (block_n, d) row blocks and verifies
-    block_n² pairs; ``tiles_visited`` defaults to the full triangle
-    (the a-priori model — pruning is data-dependent, so post-hoc
-    callers pass the kernel's realized ``tiles_pruned`` subtracted)."""
-    n_ti = max(-(-n // block_n), 1)
-    total_tiles = n_ti * (n_ti + 1) // 2
-    tiles = total_tiles if tiles_visited is None else max(tiles_visited, 0)
-    return KernelCost(
-        bytes=tiles * 2 * block_n * d * F32 + 4 * k * F32,
-        flops=tiles * (2 * block_n * block_n * d + block_n * block_n * k))
 
 
 def shard_exchange_cost(P: int, B: int, k_l: int,

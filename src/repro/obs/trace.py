@@ -1,30 +1,38 @@
-"""Hierarchical span tracer — where wall-clock time actually goes.
+"""Hierarchical span tracer on the profiler's clock.
 
 The paper argues about query cost with a model (Table 2, Eq. 7-10);
 ``WorkStats`` counts the model's units (distance computations).  This
-module records the third leg: measured wall time, per pipeline stage,
-as a tree of :class:`Span`s.
+module records the third leg: measured wall time of the host's steps,
+as a tree of :class:`Span`s, beside the device operations of a
+``jax.profiler`` trace.
 
 Design constraints (DESIGN.md §12):
 
-  * ~zero cost disabled.  One module-level boolean guards everything;
-    ``span()`` returns a shared no-op context manager without touching
-    the collector, so instrumented hot paths pay one attribute load
-    and one branch.  Engines keep their fully-jit pipelines when
-    tracing is off — the traced stage-by-stage variants only run when
-    someone asked for a trace.
-  * safe around jit.  An asynchronously dispatched jax call returns
-    before the device finishes; a span that closes without
-    synchronizing would attribute device time to whichever span
-    happens to block later.  ``block()`` calls ``block_until_ready``
-    on its arguments **only while tracing** (no-op otherwise), and the
-    kernel-dispatch instrumentation in ``repro.kernels.ops`` skips
-    span creation entirely when any argument is an abstract tracer
-    (i.e. the op is being traced *by jit*, not executed).
-  * nestable across engines.  Spans form a tree via a per-tracer
-    stack: the serve scheduler's flush span contains the streaming
-    index's fan-out spans, which contain the fused pipeline's stage
-    spans, which contain per-kernel spans carrying roofline attrs.
+  * one program.  A traced call runs the same jitted programs as an
+    untraced one; spans mark the host's steps around them (dispatch,
+    the wait for a result, copies, host sorts).  The device stages
+    inside a program carry ``jax.named_scope`` names instead, which
+    reach the profiler's op names.
+  * never blocks.  Closing a span does not wait for the device: a span
+    ends where the program itself already waits (``np.asarray`` of a
+    result), so tracing cannot move work between spans or add a sync.
+  * two switches.  A span records inside :func:`trace` (``obs.tracing``)
+    and while a ``jax.profiler`` session is active.  With neither on, a
+    span site costs one flag check and ``TraceAnnotation.is_enabled()``
+    and returns a shared no-op context manager.
+  * on the profiler's clock.  A recorded span enters a
+    ``jax.profiler.TraceAnnotation`` of its name (so it lands on the
+    host plane of the xplane, next to the device ops) and keeps an
+    in-memory :class:`Span` stamped from ``time.time_ns()``: ``t0`` /
+    ``t1`` are seconds since :data:`ORIGIN_NS`, so
+    ``to_unix_ns(t) - profile_start_time`` is a span's offset in a
+    profile (the ``Task Environment`` plane's ``profile_start_time`` is
+    on the same clock).
+  * bounded and nestable.  Past ``max_spans`` spans are counted in
+    ``dropped``; spans nest through a per-thread stack, and a child
+    inherits its parent's id attrs (:data:`ID_ATTRS`: the served
+    request's ``rid``, the facade call's ``call``), so every span of
+    one request or call carries its id.
 
 Usage::
 
@@ -37,27 +45,62 @@ Usage::
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Iterator
 
-__all__ = ["Span", "Tracer", "Trace", "get_tracer", "enabled", "enable",
-           "disable", "span", "add_span", "block", "concrete", "trace"]
+from jax.profiler import TraceAnnotation
 
-#: the one flag every instrumented call site checks first (module
-#: attribute load + truth test — the entire disabled-mode cost)
+from .metrics import get_registry
+
+__all__ = ["Span", "Tracer", "Trace", "get_tracer", "enabled", "enable",
+           "disable", "span", "add_span", "recording", "now", "to_unix_ns",
+           "new_call_id", "record_compile", "trace", "ORIGIN_NS", "ID_ATTRS"]
+
+#: the flag of ``obs.tracing()`` regions (module attribute load + truth
+#: test; the profiler check follows only when it is off)
 _ENABLED: bool = False
+
+#: True while a ``jax.profiler`` session is collecting (~40-150 ns)
+_profiling = TraceAnnotation.is_enabled
+
+#: ``time.time_ns()`` when this module was imported: span stamps are
+#: seconds since it (ns resolution in a float64 for ~100 days)
+ORIGIN_NS: int = time.time_ns()
+
+#: attrs a child span copies from its parent: one request's or one
+#: call's spans share them
+ID_ATTRS = ("rid", "call")
+
+_CALL_IDS = itertools.count()
+
+
+def now() -> float:
+    """The tracer's clock: seconds since :data:`ORIGIN_NS`."""
+    return (time.time_ns() - ORIGIN_NS) * 1e-9
+
+
+def to_unix_ns(t: float) -> int:
+    """A tracer timestamp on the ``time.time_ns()`` clock, which the
+    profiler's ``profile_start_time`` is on."""
+    return ORIGIN_NS + round(t * 1e9)
+
+
+def new_call_id() -> int:
+    """A fresh id for the spans of one facade call."""
+    return next(_CALL_IDS)
 
 
 @dataclasses.dataclass
 class Span:
     """One timed region.  ``parent`` indexes the tracer's span list
-    (-1 for roots); ``attrs`` carries whatever the site recorded —
-    kernel spans get modeled ``bytes``/``flops`` (see
-    ``repro.obs.roofline``), serve spans get shapes and reasons."""
+    (-1 for roots); ``attrs`` carries whatever the site recorded
+    (shapes, ids, work counters)."""
 
     name: str
-    t0: float  # perf_counter seconds
+    t0: float  # seconds on the tracer's clock (see ``now``)
     t1: float
     parent: int
     attrs: dict[str, Any] = dataclasses.field(default_factory=dict)
@@ -72,7 +115,7 @@ class Span:
 
 
 class _NullSpan:
-    """Shared no-op context manager returned while tracing is off."""
+    """Shared no-op context manager returned while nothing records."""
 
     __slots__ = ()
 
@@ -86,6 +129,40 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _scalars(attrs: dict) -> dict:
+    """The attrs a ``TraceAnnotation`` can carry as event stats."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (str, int, float)) and not isinstance(v, bool)}
+
+
+class _Open:
+    """A recording span: its profiler annotation and its stack entry."""
+
+    __slots__ = ("tracer", "name", "attrs", "span", "index", "note")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+        self.tracer, self.name, self.attrs = tracer, name, attrs
+
+    def __enter__(self):
+        self.span, self.index = self.tracer._open(self.name, self.attrs)
+        self.note = TraceAnnotation(self.name)
+        self.note.__enter__()
+        return self.span
+
+    def __exit__(self, *exc):
+        s = self.span
+        if s is not None:
+            s.t1 = now()
+            stack = self.tracer._stack()
+            if stack and stack[-1] == self.index:
+                stack.pop()
+            meta = _scalars(s.attrs)
+            if meta:
+                self.note.set_metadata(**meta)
+        self.note.__exit__(*exc)
+        return False
+
+
 class Tracer:
     """Process-global span collector (one per process is the intended
     use; tests may instantiate their own).  Bounded: past ``max_spans``
@@ -96,49 +173,61 @@ class Tracer:
         self.max_spans = int(max_spans)
         self.spans: list[Span] = []
         self.dropped = 0
-        self._stack: list[int] = []
+        self._local = threading.local()
 
-    # -- recording -------------------------------------------------------
+    def _stack(self) -> list[int]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
-    @contextmanager
-    def span(self, name: str, **attrs) -> Iterator[Span | None]:
-        """Open a child span of whatever span is currently on the
-        stack.  The span's end time is stamped at exit — call
-        :func:`block` on async jax results inside, or the device work
-        escapes the span."""
-        if len(self.spans) >= self.max_spans:
-            self.dropped += 1
-            yield None
-            return
-        idx = len(self.spans)
-        parent = self._stack[-1] if self._stack else -1
-        s = Span(name, time.perf_counter(), 0.0, parent, attrs)
-        self.spans.append(s)
-        self._stack.append(idx)
-        try:
-            yield s
-        finally:
-            s.t1 = time.perf_counter()
-            self._stack.pop()
-
-    def add_span(self, name: str, t0: float, t1: float, **attrs) -> Span | None:
-        """Record a span with explicit perf_counter endpoints (e.g. a
-        request's queue wait, whose start predates the current span).
-        Parented to the currently open span."""
+    def _new(self, name: str, t0: float, t1: float, attrs: dict
+             ) -> Span | None:
         if len(self.spans) >= self.max_spans:
             self.dropped += 1
             return None
-        parent = self._stack[-1] if self._stack else -1
-        s = Span(name, float(t0), float(t1), parent, attrs)
+        stack = self._stack()
+        parent = stack[-1] if stack else -1
+        if parent >= len(self.spans):  # the collector was drained
+            stack.clear()
+            parent = -1
+        if parent >= 0:
+            up = self.spans[parent].attrs
+            for key in ID_ATTRS:
+                if key in up and key not in attrs:
+                    attrs[key] = up[key]
+        s = Span(name, t0, t1, parent, attrs)
         self.spans.append(s)
         return s
+
+    def _open(self, name: str, attrs: dict) -> tuple[Span | None, int]:
+        s = self._new(name, now(), 0.0, attrs)
+        if s is None:
+            return None, -1
+        index = len(self.spans) - 1
+        self._stack().append(index)
+        return s, index
+
+    # -- recording -------------------------------------------------------
+
+    def span(self, name: str, **attrs) -> _Open:
+        """Open a child span of whatever span is open on this thread.
+        Its end is stamped at exit; nothing waits for the device."""
+        return _Open(self, name, attrs)
+
+    def add_span(self, name: str, t0: float, t1: float, **attrs) -> Span | None:
+        """Record a span with explicit endpoints on the tracer's clock
+        (e.g. a request's queue wait, whose start predates the current
+        span).  Parented to the currently open span; it has no profiler
+        annotation (one cannot be placed after the fact)."""
+        return self._new(name, float(t0), float(t1), attrs)
 
     # -- draining --------------------------------------------------------
 
     def drain(self) -> list[Span]:
         """Return collected spans and reset the collector."""
         out, self.spans = self.spans, []
-        self._stack.clear()
+        self._stack().clear()
         self.dropped = 0
         return out
 
@@ -157,6 +246,12 @@ def enabled() -> bool:
     return _ENABLED
 
 
+def recording() -> bool:
+    """True where a span opened now would be recorded: inside
+    ``obs.tracing()`` or a ``jax.profiler`` session."""
+    return _ENABLED or _profiling()
+
+
 def enable() -> None:
     global _ENABLED
     _ENABLED = True
@@ -168,47 +263,31 @@ def disable() -> None:
 
 
 def span(name: str, **attrs):
-    """Module-level span helper: a real span while tracing, the shared
-    no-op context manager otherwise."""
-    if not _ENABLED:
+    """Module-level span helper: a recording span inside
+    ``obs.tracing()`` or a profiler session, the shared no-op context
+    manager otherwise."""
+    if not _ENABLED and not _profiling():
         return _NULL_SPAN
-    return _TRACER.span(name, **attrs)
+    return _Open(_TRACER, name, attrs)
 
 
 def add_span(name: str, t0: float, t1: float, **attrs) -> Span | None:
-    if not _ENABLED:
+    if not _ENABLED and not _profiling():
         return None
     return _TRACER.add_span(name, t0, t1, **attrs)
 
 
-def concrete(*args) -> bool:
-    """True when no argument is an abstract jax tracer — i.e. we are
-    executing, not being traced by jit.  Span creation inside a jit
-    trace would time the *trace*, not the computation."""
-    try:
-        from jax.core import Tracer as _JaxTracer
-    except Exception:  # pragma: no cover - ancient jax
-        return True
-    return not any(isinstance(a, _JaxTracer) for a in args)
-
-
-def block(*values):
-    """``block_until_ready`` every jax array in ``values`` while
-    tracing (no-op otherwise).  Returns the single value or the tuple,
-    so call sites can wrap returns: ``return block(x)``."""
-    if _ENABLED:
-        for v in values:
-            _block_one(v)
-    return values[0] if len(values) == 1 else values
-
-
-def _block_one(v) -> None:
-    bur = getattr(v, "block_until_ready", None)
-    if bur is not None:
-        bur()
-    elif isinstance(v, (tuple, list)):
-        for item in v:
-            _block_one(item)
+def record_compile(event: str, duration_s: float, **_kw) -> None:
+    """``jax.monitoring`` listener: each backend compile adds one to
+    ``jit_compiles_total`` and, while recording, a ``jit.compile`` span
+    ending now, under whatever span the compile happened in."""
+    if event != "/jax/core/compile/backend_compile_duration":
+        return
+    get_registry().counter(
+        "jit_compiles_total", "XLA backend compiles in this process").inc()
+    if _ENABLED or _profiling():
+        t1 = now()
+        _TRACER.add_span("jit.compile", t1 - float(duration_s), t1)
 
 
 @dataclasses.dataclass

@@ -26,7 +26,7 @@ from repro.core.flat_index import FlatIndex
 
 from .codec import Codec
 
-__all__ = ["quant_ann_query", "quant_ann_query_traced", "quant_cp_search"]
+__all__ = ["quant_ann_query", "quant_cp_search"]
 
 
 @partial(jax.jit,
@@ -123,91 +123,6 @@ def quant_ann_query(
         negk, sel = jax.lax.top_k(-d2, k)
         idx = jnp.take_along_axis(rcand, sel, axis=1)
         out = idx.astype(jnp.int32), jnp.sqrt(jnp.maximum(-negk, 0.0))
-    return out + (cnt,) if with_count else out
-
-
-def quant_ann_query_traced(
-    index: FlatIndex,
-    codec: Codec,
-    codes: jax.Array,
-    q: jax.Array,
-    *,
-    k: int,
-    T: int,
-    R: int,
-    store_raw: bool = True,
-    force: str | None = None,
-    fused: bool = False,
-    with_count: bool = False,
-):
-    """Stage-by-stage eager twin of :func:`quant_ann_query` for tracing.
-
-    Identical math and answers; each tier runs outside jit under a
-    ``quant.*`` span (kernel spans nest underneath), so a trace shows
-    the estimate/select/ADC-rerank/verify split.  ``FlatBackend``
-    routes here only while a tracer is enabled.
-    """
-    from repro.kernels import ops as kops
-    from repro.obs import trace as otrace
-
-    tr = otrace.get_tracer()
-    assert k <= R <= T, f"need k <= R <= T, got k={k} R={R} T={T}"
-    q = jnp.asarray(q, jnp.float32)
-    if q.ndim == 1:
-        q = q[None]
-    with tr.span("quant.query", B=int(q.shape[0]),
-                 n=int(codes.shape[0]), k=k, T=T, R=R, fused=fused,
-                 store_raw=store_raw):
-        with tr.span("quant.estimate"):
-            qp = index.family.project(q)
-            d2p = kops.pairwise_sq_dist(qp, index.projected, force=force)
-        with tr.span("quant.select") as sp:
-            if fused:
-                from repro.core.fused import select_seed
-
-                m = index.params.m if index.params is not None else index.m
-                tau0 = select_seed(d2p, T, m)
-                _, cand, cnt = kops.radius_select(d2p, T, tau0=tau0,
-                                                  force=force,
-                                                  with_count=True)
-            else:
-                _, cand = jax.lax.top_k(-d2p, T)
-                cnt = jnp.full((q.shape[0],), T, jnp.int32)
-            otrace.block(cand)
-            if sp is not None:
-                sp.attrs["candidates_selected"] = int(jnp.sum(cnt))
-        with tr.span("quant.rerank"):
-            ccodes = jnp.asarray(codes)[cand]
-            direct = getattr(codec, "adc_direct", None)
-            if direct is not None:
-                d2a = direct(q, ccodes)
-            else:
-                lut = codec.lookup_tables(q)
-                d2a = kops.adc_dist(ccodes, lut, force=force)
-            if fused and R > 128:
-                adcR, selR = kops.radius_select(d2a, R, force=force)
-                negR = -adcR
-            else:
-                negR, selR = jax.lax.top_k(-d2a, R)
-            rcand = otrace.block(jnp.take_along_axis(cand, selR, axis=1))
-        with tr.span("quant.verify"):
-            if not store_raw:
-                idx = rcand[:, :k]
-                dd = jnp.sqrt(jnp.maximum(-negR[:, :k], 0.0))
-                out = (idx.astype(jnp.int32), dd)
-            elif fused:
-                d2, idx = kops.verify_topk(index.data, q, rcand, k,
-                                           force=force)
-                out = (idx.astype(jnp.int32),
-                       jnp.sqrt(jnp.maximum(d2, 0.0)))
-            else:
-                cpts = index.data[rcand]
-                d2 = kops.pairwise_sq_dist(q, cpts, force=force)
-                negk, sel = jax.lax.top_k(-d2, k)
-                idx = jnp.take_along_axis(rcand, sel, axis=1)
-                out = (idx.astype(jnp.int32),
-                       jnp.sqrt(jnp.maximum(-negk, 0.0)))
-            out = otrace.block(*out)
     return out + (cnt,) if with_count else out
 
 

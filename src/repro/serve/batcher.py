@@ -94,6 +94,9 @@ class PendingRequest:
     submit_t: float
     cache_key: Any = None  # fill the cache on completion
     degraded: bool = False
+    # submit time on the tracer's clock (repro.obs.trace.now), when the
+    # submit was traced: the start of its ``serve.queue_wait`` span
+    trace_t: float | None = None
 
 
 class Bucket:

@@ -241,6 +241,16 @@ class RequestScheduler:
         Malformed queries (NaN/Inf, wrong shape, unconvertible dtype)
         raise :class:`RejectedQuery` BEFORE entering any batch — one
         poison row must not spoil B_pad-1 neighbors."""
+        with otrace.span("serve.submit") as sp:
+            ticket, full = self._admit(query, k, deadline_ms, sp)
+        if full is not None:
+            self._flush(full, reason="full")
+        return ticket
+
+    def _admit(self, query, k, deadline_ms, sp
+               ) -> tuple[Ticket, tuple[int, str] | None]:
+        """Validate, probe the cache, admit and enqueue one query; also
+        returns the bucket key a full bucket must flush."""
         now = self.clock()
         q = self._validate_query(query)
         k = int(k if k is not None else self.step.k)
@@ -249,6 +259,8 @@ class RequestScheduler:
         self.metrics.on_submit()
         rid = self._next_id
         self._next_id += 1
+        if sp is not None:
+            sp.attrs["rid"] = rid
 
         cache_key = None
         hit = None
@@ -269,7 +281,7 @@ class RequestScheduler:
                 resp = self._respond(rid, hit, self.step, cached=True,
                                      latency_s=self.clock() - now)
                 self.metrics.on_cache_hit(resp.latency_s)
-                return Ticket(self, rid, resp)
+                return Ticket(self, rid, resp), None
             if cache_key is not None:  # real probe, not an injected error
                 self.metrics.on_cache_miss()
 
@@ -277,7 +289,7 @@ class RequestScheduler:
         if action == SHED:
             self.metrics.on_shed()
             resp = Response(rid, "shed", latency_s=self.clock() - now)
-            return Ticket(self, rid, resp)
+            return Ticket(self, rid, resp), None
 
         tier, k_serve, degraded = "primary", k, False
         if action == DEGRADE:
@@ -297,15 +309,14 @@ class RequestScheduler:
             bucket = self._buckets[bkey] = Bucket(k_pad, tier)
         bucket.add(PendingRequest(
             rid, q, k_serve, k, deadline, now,
-            cache_key=None if degraded else cache_key, degraded=degraded))
+            cache_key=None if degraded else cache_key, degraded=degraded,
+            trace_t=None if sp is None else sp.t0))
         self._pending[rid] = bkey
         # the ticket must exist (and be registered) before a full-bucket
         # flush runs, or its response would be delivered to nobody
         ticket = Ticket(self, rid)
         self._tickets[rid] = weakref.ref(ticket)
-        if len(bucket) >= self.config.b_max:
-            self._flush(bkey, reason="full")
-        return ticket
+        return ticket, (bkey if len(bucket) >= self.config.b_max else None)
 
     def _validate_query(self, query) -> np.ndarray:
         """Normalize one query to a finite float32 (d,) vector or raise
@@ -475,8 +486,11 @@ class RequestScheduler:
                 else self.step)
         b_pad = self.palette.b_pad(len(reqs))
         shape = (b_pad, k_pad)
-        with otrace.span("serve.flush", reason=reason, tier=serve_tier,
-                         b_pad=b_pad, k_pad=k_pad, real=len(reqs)) as fsp:
+        with otrace.span("serve.flush") as fsp:
+            if fsp is not None:
+                fsp.attrs.update(reason=reason, tier=serve_tier, b_pad=b_pad,
+                                 k_pad=k_pad, real=len(reqs),
+                                 rids=[r.id for r in reqs])
             self.metrics.on_flush(shape, real=len(reqs), reason=reason)
             self.metrics.on_compile(
                 hit=(b_pad, k_pad, serve_tier) in self._seen_shapes)
@@ -493,6 +507,14 @@ class RequestScheduler:
                 self.metrics.staging_reuses += 1
 
             t0 = self.clock()
+            if fsp is not None:
+                # queue wait: each request's submit to this service
+                # start, on the tracer's clock
+                t_start = otrace.now()
+                for r in reqs:
+                    if r.trace_t is not None:
+                        otrace.add_span("serve.queue_wait", r.trace_t,
+                                        t_start, rid=r.id)
             # the ladder's abandon budget: slack to the most patient
             # deadline in the batch, floored so a just-expired batch
             # still gets a real attempt
@@ -529,19 +551,13 @@ class RequestScheduler:
                                         else alpha * dt + (1 - alpha) * prev)
             self.metrics.add_work(res.stats)
             if fsp is not None:
-                # queue-wait is scheduler-clock time between submit and
-                # service start; per-request spans are only emitted
-                # under the real perf_counter clock, where the
-                # timestamps share the span timeline's epoch
+                # queue wait on the scheduler's own clock (which tests
+                # may fake), summarized on the flush
                 waits = [max(t0 - r.submit_t, 0.0) for r in reqs]
                 fsp.attrs["queue_wait_mean_ms"] = round(
                     sum(waits) / len(waits) * 1e3, 4)
                 fsp.attrs["queue_wait_max_ms"] = round(max(waits) * 1e3, 4)
                 fsp.attrs["work"] = res.stats.as_dict()
-                if self.clock is time.perf_counter:
-                    for r in reqs:
-                        otrace.add_span("serve.queue_wait", r.submit_t,
-                                        t0, rid=r.id)
 
             version = getattr(step, "version", 0)
             done_t = self.clock()

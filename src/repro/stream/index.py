@@ -277,16 +277,19 @@ class StreamingIndex(BaseIndex):
         B = q.shape[0]
         stats = WorkStats()
         id_blocks, dist_blocks = [], []
-        with otrace.span("stream.search", B=B, k=k,
-                         segments=len(self.segments),
-                         delta=len(self.delta)):
+        with otrace.span("stream.search") as sp:
+            if sp is not None:
+                sp.attrs.update(B=B, k=k, segments=len(self.segments),
+                                delta=len(self.delta))
             for si, seg in enumerate(self.segments):
                 # widen by the segment's tombstone count so filtering
                 # dead rows at merge time cannot starve the per-segment
                 # top-k
-                with otrace.span("stream.segment", serial=seg.serial,
-                                 size=seg.size, dead=seg.dead,
-                                 backend=self.segment_backend):
+                with otrace.span("stream.segment") as ssp:
+                    if ssp is not None:
+                        ssp.attrs.update(serial=seg.serial, size=seg.size,
+                                         dead=seg.dead,
+                                         backend=self.segment_backend)
                     gids, dd, st = seg.search(q, k + seg.dead)
                 id_blocks.append(gids)
                 dist_blocks.append(dd)
@@ -297,7 +300,9 @@ class StreamingIndex(BaseIndex):
                 if self.drift is not None and counts is not None:
                     self.drift.observe_survivors(
                         counts, getattr(seg.index, "last_select_budget", 0))
-            with otrace.span("stream.delta", size=len(self.delta)):
+            with otrace.span("stream.delta") as dsp:
+                if dsp is not None:
+                    dsp.attrs["size"] = len(self.delta)
                 gids, dd, st = self.delta.search(q, k, force=self._force)
             id_blocks.append(gids)
             dist_blocks.append(dd)
@@ -342,8 +347,10 @@ class StreamingIndex(BaseIndex):
         """
         from repro.core.cp_fused import cp_fused_search
 
-        with otrace.span("stream.cp_gather", segments=len(self.segments),
-                         delta=len(self.delta)):
+        with otrace.span("stream.cp_gather") as sp:
+            if sp is not None:
+                sp.attrs.update(segments=len(self.segments),
+                                delta=len(self.delta))
             blocks, gids = [], []
             for seg in self.segments:  # sealed runs first, delta last
                 live = seg.ids[self._alive[seg.ids]]

@@ -262,26 +262,6 @@ def test_nan_queries_rejected():
     assert np.all(r.indices[[0, 1, 3]] >= 0)
 
 
-def test_traced_twin_matches_and_emits_shard_spans():
-    from repro.obs import trace as otrace
-
-    data = make_clustered(150, 16, seed=10)
-    q = _queries(data, 4)
-    idx = _sharded(data, 4)
-    plain = idx.search(q, 5)
-    with otrace.trace() as tr:
-        traced = idx.search(q, 5)
-        idx.cp_search(3)
-    assert_bit_identical(plain, traced)
-    names = {s.name for s in tr.spans}
-    for want in ("shard.estimate", "shard.select", "shard.exchange",
-                 "shard.verify", "shard.merge", "shard.cp"):
-        assert want in names, f"missing span {want} in {sorted(names)}"
-    # the exchange span carries the modeled wire cost
-    ex = [s for s in tr.spans if s.name == "shard.exchange"]
-    assert all(s.attrs.get("bytes", 0) > 0 for s in ex)
-
-
 def test_registry_exposes_sharded_backends():
     names = set(available_backends())
     assert {"sharded-flat", "sharded-flat-pq"} <= names
