@@ -53,7 +53,8 @@ def select_seed(d2p: jax.Array, T: int, m: int | None) -> jax.Array:
     n = d2p.shape[1]
     if m is None or m < 1:
         return kops.default_select_seed(d2p, T)
-    samp = d2p[:, :: max(n // 4096, 1)]
+    # a strided slice: ``d2p[:, ::step]`` lowers to a gather
+    samp = jax.lax.slice(d2p, (0, 0), d2p.shape, (1, max(n // 4096, 1)))
     scale = jnp.mean(samp, axis=1) / float(m)  # Lemma-2 r̄² estimate
     q = min(max(T / n, 1e-6), 1.0 - 1e-9)
     return scale * float(chi2_ppf(q, m))

@@ -106,7 +106,7 @@ def default_select_seed(d: jax.Array, T: int, *, stride: int = 8) -> jax.Array:
     """Per-row seed for radius selection from a strided sample of d:
     the sample mean scaled by the target fraction T/N — within the
     rung ladder's reach of the T-th smallest for any unimodal row."""
-    samp = d[:, ::stride]
+    samp = jax.lax.slice(d, (0, 0), d.shape, (1, stride))  # not a gather
     N = d.shape[1]
     return jnp.mean(samp, axis=1) * jnp.float32(max(T / N, 1e-3))
 
@@ -119,7 +119,10 @@ def radius_select(d: jax.Array, T: int, *, tau0: jax.Array | None = None,
     Same contract as :func:`topk_smallest` (ascending, lowest-index
     tie-break) for any T, but O(n) threshold passes + one O(T_pad·T)
     finishing sort instead of an O(n·T) selection — the SELECT step for
-    candidate budgets in the thousands.  ``tau0`` (B,) optionally seeds
+    candidate budgets in the thousands.  The kernel hands back each
+    row's survivors already joined into a dense (B, T_pad) buffer in
+    index order, so the finish is one ``top_k`` and one gather of its
+    ids (scope ``finish``).  ``tau0`` (B,) optionally seeds
     the threshold ladder (e.g. the Eq. 9 estimate from
     ``repro.core.fused``); default is a sample-mean seed.
 

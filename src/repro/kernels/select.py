@@ -18,23 +18,31 @@ then needs no sort at all, only branch-free O(n) threshold passes:
                  T-th smallest value between two rungs;
   phases 1..I    bisection passes shrink the bracket: count(d ≤ mid)
                  vs T keeps the invariant count(lo) < T ≤ count(hi);
-  final phase    one pass packs each tile's survivors (d ≤ hi) to the
-                 front of its own output tile (``pack_front``): a 0/1
-                 mask times a triangle of ones on the MXU ranks them,
-                 a one-hot moves each to its rank.  Outside the
-                 kernel the per-tile runs are joined into a dense
-                 (B, T_pad) buffer by their running survivor totals.
+  join phase     one pass packs each tile's survivors (d ≤ hi) to the
+                 front of the tile (``pack_front``: a 0/1 mask times a
+                 triangle of ones on the MXU ranks them, a one-hot
+                 moves each to its rank) and writes the run into the
+                 row's dense buffer at the row's running survivor
+                 total, an SMEM scalar: rolled by the total mod 128
+                 lanes, into the 128-lane chunk it points at and the
+                 next.  The value and id buffers, (rows, chunks, 128),
+                 stay in VMEM across the pass and go to HBM once, at
+                 its end.  A batch whose buffers would not fit the VMEM
+                 budget is joined in row blocks, one pass each.
 
 The caller finishes with one top_k over the T_pad ≈ 1.1·T compacted
 columns (``ops.radius_select``), so total ordering work drops from
 O(n·T) to O(T_pad·T) while the threshold passes stay O(n) stream reads.
 
 Exactness: the bracket invariant guarantees every true top-T element
-survives the threshold, and compaction preserves ascending-index order,
-so the finishing top_k reproduces ``lax.top_k`` exactly — including its
+survives the threshold, and the join walks the tiles in index order and
+each run in lane order, so the buffer is ascending in index and the
+finishing top_k reproduces ``lax.top_k`` exactly — including its
 lowest-index tie-break — whenever the survivor count fits T_pad.  A
 pathological tie cluster (> T_pad − T equal values straddling the T-th
-smallest) overflows the buffer, and overflow truncates in INDEX order —
+smallest) overflows the buffer: a run that starts at slot T_pad or
+later lands in spill chunks that are never read, so overflow truncates
+in INDEX order —
 the dropped high-index survivors may be strictly nearer than kept ones,
 so an overflowed buffer is NOT a valid candidate set.  The kernel
 therefore yields the exact per-row survivor counts and the dispatch
@@ -55,12 +63,12 @@ __all__ = ["radius_select_kernel", "radius_select_pallas", "pack_front",
 
 
 def radius_select_kernel(
-    tau0_ref, d_ref, ov_ref, oi_ref,
-    cnt_ref, lad_ref, lo_ref, hi_ref, dmax_ref,
-    *, T: int, block_n: int, L: int, L0: int, c2: float, iters: int,
-    n_tiles: int, Bh: int,
+    tau0_ref, d_ref, ov_hbm, oi_hbm, ocnt_ref,
+    cnt_ref, lad_ref, lo_ref, hi_ref, dmax_ref, vbuf, ibuf, off_ref, sem,
+    *, T: int, T_pad: int, block_n: int, L: int, L0: int, c2: float,
+    iters: int, n_tiles: int, Bh: int, R: int,
 ):
-    p = pl.program_id(0)  # phase: 0 ladder, 1..iters bisect, last compact
+    p = pl.program_id(0)  # 0 ladder, 1..iters bisect, then one join a block
     j = pl.program_id(1)  # tile along n
     last = n_tiles - 1
     d = d_ref[...]  # (Bh, bN), padding carries +inf
@@ -127,19 +135,75 @@ def radius_select_kernel(
                                     jnp.broadcast_to(mid, lo_ref.shape))
             cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    # -- final phase: pack each tile's survivors (d <= hi) to its front ----
-    @pl.when(p == iters + 1)
-    def _compact():
+    # -- then one pass per block of R rows: join the survivors (d <= hi) --
+    # into each row's buffer at its running survivor total
+    @pl.when(p > iters)
+    def _join():
+        base = (p - iters - 1) * R  # first row of this block
+
+        @pl.when(j == 0)
+        def _reset():
+            def row(r, _):
+                off_ref[r] = 0
+                vbuf[r] = jnp.full(vbuf.shape[1:], jnp.inf, jnp.float32)
+                ibuf[r] = jnp.full(ibuf.shape[1:], -1, jnp.int32)
+                return 0
+
+            jax.lax.fori_loop(0, R, row, 0)
+
+        chunk = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+        spill = -(-T_pad // 128)  # the first chunk wholly past T_pad
+
         def group(g, _):  # 8 rows at a time bound the (8, bN, bN) one-hot
             r = pl.multiple_of(g * 8, 8)
-            dg = d_ref[pl.ds(r, 8), :]
-            mask = (dg <= hi_ref[pl.ds(r, 8), :1]) & (dg < jnp.inf)
+            rows = pl.ds(pl.multiple_of(base + r, 8), 8)
+            dg = d_ref[rows, :]
+            mask = (dg <= hi_ref[rows, :1]) & (dg < jnp.inf)
             vals, src, keep = pack_front(dg, mask)
-            ov_ref[pl.ds(r, 8), :] = jnp.where(keep, vals, jnp.inf)
-            oi_ref[pl.ds(r, 8), :] = jnp.where(keep, j * block_n + src, -1)
+            vals = jnp.where(keep, vals, jnp.inf)
+            ids = jnp.where(keep, j * block_n + src, -1)
+            n = jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True)
+            cnt_ref[rows, :] += jnp.broadcast_to(n.astype(jnp.float32),
+                                                 (8, 128))
+            # row i's slots [off, off + n) start s lanes into chunk c:
+            # each 128 lanes of its run, rolled by s, fill the rest of
+            # one chunk and the head of the next.  A run that starts at
+            # T_pad or later goes to chunks past T_pad, which are never
+            # read: the count stays exact, so the caller sees the
+            # overflow.  All loads come before all stores, so the rows'
+            # writes need not wait on one another
+            offs = [off_ref[r + i] for i in range(8)]
+            for i in range(8):
+                off_ref[r + i] = offs[i] + jnp.sum(n[i:i + 1])
+            cs = [jnp.minimum(o // 128, spill) for o in offs]
+            heads = [chunk >= o % 128 for o in offs]
+            old = [[buf[r + i, pl.ds(cs[i], 1), :] for i in range(8)]
+                   for buf in (vbuf, ibuf)]
+            for buf, run, pad, first in ((vbuf, vals, jnp.inf, old[0]),
+                                         (ibuf, ids, -1, old[1])):
+                for i in range(8):
+                    moved = [first[i]] + [
+                        pltpu.roll(run[i:i + 1, q:q + 128], offs[i] % 128, 1)
+                        for q in range(0, block_n, 128)]
+                    moved.append(jnp.full_like(first[i], pad))
+                    for q in range(block_n // 128 + 1):
+                        buf[r + i, pl.ds(cs[i] + q, 1), :] = jnp.where(
+                            heads[i], moved[q + 1], moved[q])
             return 0
 
-        jax.lax.fori_loop(0, Bh // 8, group, 0)
+        jax.lax.fori_loop(0, R // 8, group, 0)
+
+        @pl.when(j == last)
+        def _flush():
+            copies = [pltpu.make_async_copy(buf, out.at[pl.ds(base, R)],
+                                            sem.at[q])
+                      for q, (buf, out) in enumerate(((vbuf, ov_hbm),
+                                                      (ibuf, oi_hbm)))]
+            for cp in copies:
+                cp.start()
+            for cp in copies:
+                cp.wait()
+            ocnt_ref[...] = cnt_ref[...]  # the last block's write holds all
 
 
 def tile_ranks(mask: jax.Array) -> jax.Array:
@@ -173,6 +237,13 @@ def pack_front(vals: jax.Array, mask: jax.Array):
     keep = (jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
             < jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True))
     return packed, lanes, keep
+
+
+# VMEM held by the join's value and id buffers: rows × slots × 8 bytes.
+# A batch whose buffers exceed it is joined in row blocks that fit, one
+# more pass over the tiles each (v5e: 128 MiB of VMEM a core)
+_BUFFER_BYTES = 64 * 1024**2
+_VMEM_SPARE = 16 * 1024**2  # the pipelined tiles and the one-hot
 
 
 @functools.partial(
@@ -216,75 +287,77 @@ def radius_select_pallas(
     true top-T members — callers MUST discard it and fall back to an
     exact selection (the dispatch wrapper does; see module doc).
     """
+    return _radius_select(d, tau0, T, T_pad=T_pad, block_n=block_n,
+                          ladder=ladder, iters=iters, c2=c2,
+                          interpret=interpret, buffer_bytes=_BUFFER_BYTES)
+
+
+def _radius_select(d, tau0, T, *, T_pad, block_n, ladder, iters, c2,
+                   interpret, buffer_bytes):
     B, N = d.shape
     assert 1 <= T <= N, f"T={T} out of range for N={N}"
     assert T_pad >= T, f"T_pad={T_pad} < T={T}"
     L = min(ladder, 128)
     bN = min(block_n, _ceil_mult(N, 128))
-    Bh = _ceil_mult(B, 8)
+    assert bN % 128 == 0, f"block_n={block_n} is not a multiple of 128"
     Np = _ceil_mult(N, bN)
     n_tiles = Np // bN
-    P = iters + 2
+    # a row's buffer: its T_pad slots in 128-lane chunks, room for a
+    # tile's run that starts in the last of them, and the spill chunks
+    # where the runs past T_pad land; whole (8, 128) tiles, so the VMEM
+    # buffer and its HBM copy have one size
+    C = _ceil_mult(-(-T_pad // 128) + bN // 128 + 1, 8)
+    row_bytes = C * 128 * 8
+    R, blocks = _row_blocks(_ceil_mult(B, 8), buffer_bytes // row_bytes)
+    Bh = R * blocks
+    P = iters + 1 + blocks
     kern = functools.partial(
-        radius_select_kernel, T=T, block_n=bN, L=L, L0=L // 2,
-        c2=c2, iters=iters, n_tiles=n_tiles, Bh=Bh)
-    # the packed tiles are written only in the last phase; before it the
-    # output block index stays put, so nothing is written back early
-    out_tile = pl.BlockSpec(
-        (Bh, bN), lambda p, j: (0, jnp.where(p == P - 1, j, 0)))
+        radius_select_kernel, T=T, T_pad=T_pad, block_n=bN, L=L, L0=L // 2,
+        c2=c2, iters=iters, n_tiles=n_tiles, Bh=Bh, R=R)
     with jax.named_scope("radius"):
         dp = jnp.full((Bh, Np), jnp.inf, jnp.float32).at[:B, :N].set(d)
         t0 = jnp.zeros((Bh, 128), jnp.float32).at[:B, :].set(
             jnp.broadcast_to(
                 jnp.maximum(jnp.asarray(tau0, jnp.float32), 1e-30)[:, None],
                 (B, 128)))
-        vals_t, idx_t = pl.pallas_call(
+        rows = pl.BlockSpec((Bh, 128), lambda p, j: (0, 0))
+        vals, idx, count = pl.pallas_call(
             kern,
             grid=(P, n_tiles),
-            in_specs=[
-                pl.BlockSpec((Bh, 128), lambda p, j: (0, 0)),
-                pl.BlockSpec((Bh, bN), lambda p, j: (0, j)),
-            ],
-            out_specs=[out_tile, out_tile],
+            in_specs=[rows, pl.BlockSpec((Bh, bN), lambda p, j: (0, j))],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                       pl.BlockSpec(memory_space=pl.ANY), rows],
             out_shape=[
-                jax.ShapeDtypeStruct((Bh, Np), jnp.float32),
-                jax.ShapeDtypeStruct((Bh, Np), jnp.int32),
+                jax.ShapeDtypeStruct((Bh, C, 128), jnp.float32),
+                jax.ShapeDtypeStruct((Bh, C, 128), jnp.int32),
+                jax.ShapeDtypeStruct((Bh, 128), jnp.float32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((Bh, 128), jnp.float32),  # bisection count
+                pltpu.VMEM((Bh, 128), jnp.float32),  # pass / survivor count
                 pltpu.VMEM((Bh, 128), jnp.float32),  # ladder counts (lane l)
                 pltpu.VMEM((Bh, 128), jnp.float32),  # bracket lo
                 pltpu.VMEM((Bh, 128), jnp.float32),  # bracket hi
                 pltpu.VMEM((Bh, 128), jnp.float32),  # running data max
+                pltpu.VMEM((R, C, 128), jnp.float32),  # joined values
+                pltpu.VMEM((R, C, 128), jnp.int32),  # joined ids
+                pltpu.SMEM((R,), jnp.int32),  # each row's running total
+                pltpu.SemaphoreType.DMA((2,)),  # the buffers' copies out
             ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=R * row_bytes + _VMEM_SPARE),
             interpret=interpret,
             name="radius_select_pallas",
         )(t0, dp)
-    return _concat_tiles(vals_t[:B], idx_t[:B], bN, T_pad)
+    vals = vals.reshape(Bh, C * 128)[:B, :T_pad]
+    idx = idx.reshape(Bh, C * 128)[:B, :T_pad]
+    return vals, idx, count[:B, 0].astype(jnp.int32)
 
 
-@jax.named_scope("compact")
-def _concat_tiles(vals_t, idx_t, bN: int, T_pad: int):
-    """Join each row's front-packed tile runs into its first T_pad slots.
-
-    vals_t / idx_t: (B, n_tiles·bN), tile t's survivors packed to the
-    front of its bN columns, (+inf, -1) behind them.  Slot s of a row
-    is read from the tile whose running survivor total first exceeds s.
-    """
-    B, Np = idx_t.shape
-    per_tile = jnp.sum(idx_t.reshape(B, Np // bN, bN) >= 0, axis=2,
-                       dtype=jnp.int32)  # (B, n_tiles)
-    upto = jnp.cumsum(per_tile, axis=1)  # survivors in tiles 0..t
-    count = upto[:, -1]
-    slot = jnp.arange(T_pad, dtype=jnp.int32)
-    tile = jax.vmap(lambda u: jnp.searchsorted(u, slot, side="right"))(upto)
-    tile = jnp.minimum(tile, Np // bN - 1).astype(jnp.int32)
-    start = jnp.take_along_axis(upto - per_tile, tile, axis=1)
-    col = tile * bN + slot[None, :] - start
-    live = slot[None, :] < count[:, None]
-    vals = jnp.where(live, jnp.take_along_axis(vals_t, col, axis=1), jnp.inf)
-    idx = jnp.where(live, jnp.take_along_axis(idx_t, col, axis=1), -1)
-    return vals, idx, count
+def _row_blocks(rows: int, fit: int) -> tuple[int, int]:
+    """Split ``rows`` (a multiple of 8) into the fewest blocks of at most
+    ``fit`` rows: (rows a block, a multiple of 8; blocks)."""
+    blocks = -(-rows // max(fit // 8 * 8, 8))
+    return _ceil_mult(-(-rows // blocks), 8), blocks
 
 
 def _ceil_mult(v: int, m: int) -> int:

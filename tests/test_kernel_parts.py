@@ -1,7 +1,10 @@
 """Interpret-mode parity of the kernel building blocks that the TPU
 compiler forced: the masked-reduction selection network shared by the
 topk, verify and pair-join kernels, the matmul rank that replaced a
-cumsum in the radius select, and the select's tiled output."""
+cumsum in the radius select, and the select's in-kernel join of each
+tile's survivors into the row's buffer."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +12,8 @@ import pytest
 from jax.experimental import pallas as pl
 
 from repro.kernels import ops, ref
-from repro.kernels.select import _concat_tiles, pack_front, tile_ranks
+from repro.kernels import select
+from repro.kernels.select import pack_front, tile_ranks
 from repro.kernels.topk import smallest_k
 
 
@@ -140,32 +144,75 @@ class TestSelectParts:
             assert np.asarray(keep)[r, :c].all()
             assert not np.asarray(keep)[r, c:].any()
 
-    def test_concat_tiles_matches_compaction(self):
-        """Per-tile front-packed runs joined in index order, truncated
-        at T_pad, with the exact survivor count."""
-        rng = np.random.default_rng(5)
-        B, bN, n_tiles, T_pad = 3, 128, 6, 200
-        mask = rng.random((B, n_tiles * bN)) < 0.3
-        mask[2] = False  # a row with no survivors at all
-        d = rng.random((B, n_tiles * bN)).astype(np.float32)
-        vals_t = np.full_like(d, np.inf)
-        idx_t = np.full(d.shape, -1, np.int32)
+    @pytest.mark.parametrize("case", ["no_survivors", "overflow", "straddle",
+                                      "ragged", "row_split"])
+    def test_join_matches_compaction(self, case):
+        """The kernel's joined buffer is each row's survivors in index
+        order, truncated at T_pad, with the exact survivor count."""
+        rng = np.random.default_rng(len(case))
+        budget = select._BUFFER_BYTES
+        if case == "no_survivors":  # a row of padding only
+            B, n, T, T_pad = 3, 700, 40, 120
+            d = rng.random((B, n)).astype(np.float32)
+            d[1] = np.inf
+        elif case == "overflow":  # a tie cluster wider than the buffer
+            B, n, T, T_pad = 2, 2000, 10, 300
+            d = rng.random((B, n)).astype(np.float32)
+            d[0] = 5.0
+            d[0, 1997:] = 0.5
+        elif case == "straddle":  # full 128-survivor tiles at any shift
+            B, n, T, T_pad = 3, 1024, 400, 520
+            d = rng.uniform(10.0, 20.0, (B, n)).astype(np.float32)
+            for b, start in enumerate((50, 128, 5)):
+                d[b, start:start + T] = rng.random(T)
+        elif case == "ragged":  # B not a multiple of 8, n not of the tile
+            B, n, T, T_pad = 5, 1000, 120, 250
+            d = (rng.normal(size=(B, n)) ** 2 * 3).astype(np.float32)
+        else:  # buffers for 8 rows only: the batch joins in row blocks
+            B, n, T, T_pad = 20, 600, 50, 130
+            d = (rng.normal(size=(B, n)) ** 2 * 3).astype(np.float32)
+            budget = 8 * 8 * 128 * 8  # 8 rows of 3 chunks, 8-row padded
+            assert select._row_blocks(24, 8) == (8, 3)
+        tau0 = np.nanmean(np.where(np.isinf(d), np.nan, d), axis=1) * T / n
+        run = jax.jit(functools.partial(
+            select._radius_select, T=T, T_pad=T_pad, block_n=128,
+            ladder=16, iters=14, c2=2.25, interpret=True,
+            buffer_bytes=budget))
+        vals, idx, count = map(np.asarray, run(
+            jnp.asarray(d), jnp.asarray(np.nan_to_num(tau0, nan=1.0),
+                                        jnp.float32)))
+        assert vals.shape == idx.shape == (B, T_pad) and count.shape == (B,)
         for b in range(B):
-            for t in range(n_tiles):
-                cols = t * bN + np.flatnonzero(mask[b, t * bN:(t + 1) * bN])
-                vals_t[b, t * bN:t * bN + cols.size] = d[b, cols]
-                idx_t[b, t * bN:t * bN + cols.size] = cols
-        vals, idx, count = _concat_tiles(jnp.asarray(vals_t),
-                                         jnp.asarray(idx_t), bN, T_pad)
-        for b in range(B):
-            cols = np.flatnonzero(mask[b])
-            assert int(count[b]) == cols.size
+            kept = idx[b][idx[b] >= 0]
+            thr = d[b, kept].max() if kept.size else -np.inf
+            cols = np.flatnonzero((d[b] <= thr) & np.isfinite(d[b]))
+            assert count[b] == cols.size
             c = min(cols.size, T_pad)
-            np.testing.assert_array_equal(np.asarray(idx)[b, :c], cols[:c])
-            np.testing.assert_array_equal(np.asarray(vals)[b, :c],
-                                          d[b, cols[:c]])
-            assert (np.asarray(idx)[b, c:] == -1).all()
-            assert np.isinf(np.asarray(vals)[b, c:]).all()
+            np.testing.assert_array_equal(idx[b, :c], cols[:c])
+            np.testing.assert_array_equal(vals[b, :c], d[b, cols[:c]])
+            assert (idx[b, c:] == -1).all()
+            assert np.isinf(vals[b, c:]).all()
+        if case == "no_survivors":
+            assert count[1] == 0
+        if case == "overflow":
+            assert count[0] == n > T_pad
+        if case == "straddle":  # row 0 joins runs of 78, 128, 128 and 66
+            assert (count == T).all()
+
+    @pytest.mark.parametrize("rows,T_pad,want", [
+        (64, 108_792, (64, 1)),  # a batch of 64 at n = 1M fits at once
+        (16, 108_792, (16, 1)),
+        (128, 108_792, (64, 2)),
+        (72, 300_000, (24, 3)),
+        (8, 10_000_000, (8, 1)),  # never fewer than 8 rows a block
+    ])
+    def test_row_blocks_fit_the_buffer_budget(self, rows, T_pad, want):
+        C = -(-(-(-T_pad // 128) + 2) // 8) * 8  # a run's room, a spill
+        row_bytes = C * 128 * 8
+        got = select._row_blocks(rows, select._BUFFER_BYTES // row_bytes)
+        assert got == want
+        R, blocks = got
+        assert R % 8 == 0 and R * blocks >= rows > R * (blocks - 1)
 
     @pytest.mark.parametrize("force", ["interpret", "ref"])
     def test_select_ids_past_bf16_range(self, force):

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 N, D, B, K, M = 1_000_000, 256, 64, 10, 15
+SERVE_K = 16  # the scheduler's k_max: a served flush searches at it
 HBM_BYTES = 16 * 1024**3  # one v5e chip
 
 
@@ -85,8 +86,13 @@ def _cases():
     from repro.kernels.topk import topk_smallest_pallas
     from repro.kernels.verify import verify_topk_pallas
 
+    def select(b, k):  # the select at the budget of a batch of b at k
+        T = candidate_budget(solve_parameters(1.5, m=M), N, k)
+        T_pad = T + max(256, T // 8)
+        return (lambda d, tau0: radius_select_pallas(d, tau0, T, T_pad=T_pad),
+                [(b, N), (b,)])
+
     T = candidate_budget(solve_parameters(1.5, m=M), N, K)
-    T_pad = T + max(256, T // 8)
     return {
         "pairwise_estimate": (
             lambda q, x: pairwise_sq_dist_pallas(q, x),
@@ -94,9 +100,10 @@ def _cases():
         "pairwise_exact": (
             lambda q, x: pairwise_sq_dist_pallas(q, x),
             [(B, D), (N, D)]),
-        "select": (
-            lambda d, tau0: radius_select_pallas(d, tau0, T, T_pad=T_pad),
-            [(B, N), (B,)]),
+        "select": select(B, K),
+        # the served path's flush shapes (b_max 16, k_max 16)
+        "select_b1": select(1, SERVE_K),
+        "select_b16": select(16, SERVE_K),
         "verify": (
             lambda data, q, cand: verify_topk_pallas(data, q, cand, K),
             [(N, D), (B, D), ((B, T), jnp.int32)]),
@@ -111,7 +118,8 @@ def _cases():
 
 
 @pytest.mark.parametrize("name", ["pairwise_estimate", "pairwise_exact",
-                                  "select", "verify", "topk", "pair_join"])
+                                  "select", "select_b1", "select_b16",
+                                  "verify", "topk", "pair_join"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, specs = _cases()[name]
     _compile(fn, *_shapes(specs, one_chip))
@@ -218,29 +226,65 @@ def test_verify_row_view_is_one_unpadded_copy(one_chip):
     assert data_bytes <= temp < 1.05 * data_bytes, temp
 
 
-def test_fused_search_program_compiles_for_v5e(one_chip):
-    """The whole jitted estimate → select → verify program at n = 1M,
-    with the kernels forced, as a chip's default dispatch runs it."""
+@pytest.fixture(scope="module")
+def fused_search(one_chip):
+    """``(b, k) -> program``: the whole jitted estimate → select → verify
+    program at n = 1M for a batch of b at k, with the kernels forced, as
+    a chip's default dispatch runs it; each compiled once a module."""
     from repro.core.estimator import solve_parameters
     from repro.core.flat_index import FlatIndex, candidate_budget
     from repro.core.fused import fused_ann_query
     from repro.core.hashing import ProjectionFamily
 
     params = solve_parameters(1.5, m=M)
-    T = candidate_budget(params, N, K)
 
     def s(shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
     index = FlatIndex(data=s((N, D)), projected=s((N, M)),
                       family=ProjectionFamily(a=s((D, M))), params=params)
-    compiled = _compile(
-        lambda idx, q: fused_ann_query(idx, q, k=K, T=T, force="pallas",
-                                       with_count=True),
-        index, s((B, D)))
+    programs = {}
+
+    def get(b, k):
+        if (b, k) not in programs:
+            T = candidate_budget(params, N, k)
+            assert np.isfinite(T) and T > k
+            programs[b, k] = _compile(
+                lambda idx, q: fused_ann_query(idx, q, k=k, T=T,
+                                               force="pallas",
+                                               with_count=True),
+                index, s((b, D)))
+        return programs[b, k]
+
+    return get
+
+
+def test_fused_search_program_compiles_for_v5e(fused_search):
     # estimate, select and verify each run as a kernel
-    assert compiled.as_text().count("tpu_custom_call") >= 3
-    assert np.isfinite(T) and T > K
+    assert fused_search(B, K).as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("b,k", [(B, K), (1, SERVE_K), (16, SERVE_K)])
+def test_select_joins_survivors_without_loops_or_gathers(fused_search, b, k):
+    """The select kernel joins each tile's survivors into the row's
+    buffer itself: under the ``select`` scope the compiled program has
+    no while loop, and no gather outside ``select/finish`` (whose trim
+    takes the top-k's ids with one gather)."""
+    import re
+
+    found = []
+    for line in fused_search(b, k).as_text().splitlines():
+        op = re.search(r"\s(while|gather)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if op and name:
+            scope = "/".join(p for p in name.group(1).split("/")
+                             if not p.startswith("jit("))
+            found.append((op.group(1), scope))
+    in_select = [(op, sc) for op, sc in found if sc.startswith("select/")]
+    assert not [f for f in in_select if f[0] == "while"], in_select
+    assert not [f for f in in_select if f[0] == "gather"
+                and not f[1].startswith("select/finish/")], in_select
+    assert ("gather", "select/finish/") in {(op, sc[:14]) for op, sc in found}
 
 
 # -- the profiler's names: stage scopes and kernel names ---------------------
@@ -270,8 +314,7 @@ def _programs(one_chip):
             idx, q, k=K, T=T, fused=True, force="pallas", with_count=True),
             (index, s((B, D))),
             ["estimate", "select", "select/seed", "select/radius",
-             "select/compact", "select/finish", "verify", "verify/relayout",
-             "verify/kernel"]),
+             "select/finish", "verify", "verify/relayout", "verify/kernel"]),
         "unfused": (lambda idx, q: ann_query(
             idx, q, k=K, T=T, fused=False, force="pallas"),
             (index, s((B, D))), ["estimate", "select", "verify"]),
