@@ -175,11 +175,10 @@ class Batch:
                                     for r in rows])
         self.ids, self.dists = np.concatenate(ids), np.concatenate(dists)
         n = self.x_host.shape[0]
-        impl = self.index.impl
         from_T = getattr(self.index, "last_select_budget", None)
         counters = {"ann": {"calls": calls, "B": B, "k": k, "n": n,
                             "d": self.x_host.shape[1],
-                            "m": int(impl.projected.shape[1]),
+                            "m": int(self.cfg["index"]["m"]),
                             "T": int(from_T) if from_T else None,
                             "rerouted": sum(rerouted)}}
         fast = min(times)

@@ -1,5 +1,5 @@
-"""select (``kernels/select.py``, ``_concat_tiles`` and the finishing
-top-k in ``kernels/ops.py``): the T nearest estimates of each query.
+"""select (``kernels/select.py`` and the finishing top-k in
+``kernels/ops.py``): the T nearest estimates of each query.
 Work per call, whatever the implementation: one read of the (B, n)
 estimates, B·T ids and values written, B·n compares.  Time: in each run
 of the search program, the device time from the end of the estimate

@@ -125,14 +125,14 @@ def peaks_for(kind: str, root: Path = ROOT) -> dict:
     return table["devices"][kind]
 
 
-def per_layer(spec, cell, win, trace, peaks) -> dict:
+def per_layer(spec, cell, win, trace, peaks, root: Path = ROOT) -> dict:
     from types import SimpleNamespace
 
     ctx = SimpleNamespace(trace=trace, counters=win.counters, peaks=peaks,
                           window_s=win.seconds)
     out = {}
     for m in cell_metrics(spec, cell.name, True):
-        value = load_reader(m["name"]).read(ctx)
+        value = load_reader(m["name"], root).read(ctx)
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out
@@ -156,29 +156,31 @@ def log(msg: str) -> None:
 
 def run(args, *, devices=None, cfg_override: dict | None = None,
         options: dict | None = None, patch=None,
-        compile_cache: bool = True) -> dict:
+        compile_cache: bool = True, root: Path = ROOT) -> dict:
     """One run of one cell; returns the result line's object.
 
     ``devices`` stands in for the accelerator check, ``cfg_override``
     for configuration keys and ``options`` for index options (tests run
     a cell's whole path on the CPU at a small size with them, and leave
     the process's compile cache alone with ``compile_cache=False``);
-    ``patch(driver)`` may replace part of the timed path after set-up.
+    ``patch(driver)`` may replace part of the timed path after set-up;
+    ``root`` is the directory that holds ``BENCHMARK.json`` and the
+    cells' files (tests point it at a copy with cells of their own).
     """
     import jax
 
     import drivers
     from check import judge, load_limits
 
-    spec = load_spec()
-    cell = load_cell(spec, args.workload, args.seed)
+    spec = load_spec(root)
+    cell = load_cell(spec, args.workload, args.seed, root)
     if devices is None:
         devices = accelerator(cell.chips)
     cell.cfg.update(cfg_override or {})
     cell.options = dict(options or {})
     cache = enable_cache() if compile_cache else None
     info = device_info(devices)
-    peaks = peaks_for(info["kind"]) if args.trace else None
+    peaks = peaks_for(info["kind"], root) if args.trace else None
     log(f"cell={cell.name} seed={args.seed} seconds={args.seconds} "
         f"trace={args.trace} device={info['kind']} x{info['count']} "
         f"jax={jax.__version__} cache={cache}")
@@ -195,7 +197,7 @@ def run(args, *, devices=None, cfg_override: dict | None = None,
 
     trace_dir = None
     if args.trace:
-        trace_dir = Path(args.trace_dir or ROOT / ".bench_trace" / cell.name)
+        trace_dir = Path(args.trace_dir or root / ".bench_trace" / cell.name)
         shutil.rmtree(trace_dir, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
@@ -216,7 +218,7 @@ def run(args, *, devices=None, cfg_override: dict | None = None,
         tr = devtrace.Trace.from_dir(str(trace_dir))
         busy = tr.busy_s()
         info.update(busy_s=busy, window_s=tr.window_s)
-        metrics = per_layer(spec, cell, win, tr, peaks)
+        metrics = per_layer(spec, cell, win, tr, peaks, root)
         result["breakdown"] = {"device_ops": tr.top_ops(10),
                                "idle_gaps": tr.idle_gaps(10)}
         if not args.trace_dir:
@@ -226,7 +228,7 @@ def run(args, *, devices=None, cfg_override: dict | None = None,
     t0 = time.perf_counter()
     numbers, measured = drv.check(control=bool(args.control))
     log(f"reference_s={time.perf_counter() - t0:.3f}")
-    correct, compared = judge(numbers, load_limits(ROOT, cell.name))
+    correct, compared = judge(numbers, load_limits(root, cell.name))
     for name in sorted(set(numbers) - set(compared)):
         log(f"read {name}={numbers[name]!r} (not compared)")
 

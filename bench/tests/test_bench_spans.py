@@ -1,5 +1,6 @@
 """The readers of the program's own names on a synthetic run: device ops
 by their named scopes, and program spans from ``repro.obs.trace``."""
+import re
 import sys
 from pathlib import Path
 from types import SimpleNamespace
@@ -31,25 +32,21 @@ def tracer():
     tr.drain()
 
 
-def _profile(with_scopes=True):
+def _profile():
     """A serialized profile of one search: the ops' name stacks sit in
     their event metadata, as on a TPU, one of them interned."""
     from jax.profiler import ProfileData
 
-    sel = SEL if with_scopes else "jit(ann_query)/jit(fused_ann_query)"
     ops = [  # (name, name stack, start ms, end ms)
         ("%pairwise_sq_dist_pallas.1", "jit(ann_query)/jit(fused_ann_query)/"
          "estimate/jit(pairwise_sq_dist_pallas)/pairwise_sq_dist_pallas/"
          "pallas_call:", 0, 1),
-        ("%radius_select_pallas.1", sel + "/jit(radius_select_pallas)/radius/"
+        ("%radius_select_pallas.1", SEL + "/jit(radius_select_pallas)/radius/"
          "radius_select_pallas/pallas_call:", 1, 2),
-        # the compaction loop and, nested in it, its body
-        ("%while.39", sel + "/jit(radius_select_pallas)/compact/"
-         "vmap(jit(searchsorted))/vmap()/while:", 2, 6),
-        ("%fusion.28", sel + "/jit(radius_select_pallas)/compact/"
-         "vmap(jit(searchsorted))/vmap()/while/body/closed_call/gather:",
-         3, 5),
-        ("%cond.1", sel + "/finish/cond:", 6, 8),
+        # the exact sort's reroute and, nested in it, its branch
+        ("%cond.1", SEL + "/finish/cond:", 2, 6),
+        ("%sort.5", SEL + "/finish/cond/branch_1_fun/sort:", 3, 5),
+        ("%fusion.28", SEL + "/finish/slice:", 6, 8),
         ("%verify_topk_pallas.1", "jit(ann_query)/jit(fused_ann_query)/"
          "verify/jit(verify_topk_pallas)/kernel/verify_topk_pallas/"
          "pallas_call:", 8, 9)]
@@ -66,7 +63,7 @@ def _profile(with_scopes=True):
             f'{" ".join(events)} }} {" ".join(meta)} '
             f'stat_metadata {{ key: 7 value {{ id: 7 name: "tf_op" }} }} '
             f'stat_metadata {{ key: 100 value {{ id: 100 '
-            f'name: "{sel}/finish/cond:" }} }} }} '
+            f'name: "{SEL}/finish/cond:" }} }} }} '
             f'planes {{ id: 2 name: "/host:CPU" lines {{ id: 1 name: "python" '
             f'timestamp_ns: 0 events {{ metadata_id: 1 offset_ps: 0 '
             f'duration_ps: {10 * MS * 1000} }} }} event_metadata {{ key: 1 '
@@ -79,38 +76,26 @@ def test_device_ops_carry_their_name_stacks():
 
     devices, spans = xplane.read(_profile())
     (ops,) = devices.values()
-    assert [o.name for o in ops][-2:] == ["%cond.1", "%verify_topk_pallas.1"]
-    assert ops[4].tf_op == SEL + "/finish/cond:"  # the interned string
+    assert [o.name for o in ops][-2:] == ["%fusion.28",
+                                          "%verify_topk_pallas.1"]
+    assert ops[2].tf_op == SEL + "/finish/cond:"  # the interned string
     assert (ops[2].start, ops[2].end) == (2 * MS, 6 * MS)
     assert xplane.window(spans) == (0, 10 * MS)
-
-
-def test_compact_share_is_the_loop_over_select():
-    import xplane
-
-    # select: [1, 8] ms = 7 ms; compact: the loop [2, 6], its body
-    # inside it counted once = 4 ms
-    devices, spans = xplane.read(_profile())
-    assert reader("select.compact_share").share(
-        devices, xplane.window(spans)) == pytest.approx(100.0 * 4 / 7)
-
-
-def test_compact_share_without_scopes_or_profile_is_none(tmp_path):
-    import xplane
-
-    devices, spans = xplane.read(_profile(with_scopes=False))
-    assert reader("select.compact_share").share(
-        devices, xplane.window(spans)) is None
-    assert reader("select.compact_share").read(
-        SimpleNamespace(trace=None)) is None
-    # a devtrace.Trace whose profile is not under <root>/.bench_trace
-    tr = Trace({}, [("bench.window", 0, 10 * MS)])
-    assert xplane.this_runs_profile(tr, str(tmp_path)) is None
+    # a scope's seconds are the union of its ops: select [1, 8] ms; the
+    # reroute [2, 6] and its branch inside it count once
+    win = xplane.window(spans)
+    assert xplane.scope_seconds(devices, win, re.compile(
+        r"(^|/)select/")) == pytest.approx(7e-3)
+    assert xplane.scope_seconds(devices, win, re.compile(
+        r"/finish/cond")) == pytest.approx(4e-3)
 
 
 def test_the_runs_profile_is_found_by_its_window(tmp_path):
     import xplane
 
+    # none under <root>/.bench_trace yet
+    assert xplane.this_runs_profile(
+        Trace({}, [("bench.window", 0, 10 * MS)]), str(tmp_path)) is None
     keep = tmp_path / ".bench_trace" / "cell" / "plugins" / "profile" / "t"
     keep.mkdir(parents=True)
     (keep / "host.xplane.pb").write_bytes(_profile())

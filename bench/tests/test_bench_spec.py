@@ -20,6 +20,14 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 TEXT = re.compile(r"^[^\n\t]{1,200}$")
 
 
+def chips_allowed(workloads) -> bool:
+    """Every cell takes 1 or 4 chips, and at most half of the cells,
+    rounded down, take 4 (one always may)."""
+    chips = [w["chips"] for w in workloads]
+    four = sum(c == 4 for c in chips)
+    return set(chips) <= {1, 4} and four <= max(len(chips) // 2, 1)
+
+
 def test_top_level_keys():
     assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
                          "workloads", "end_to_end", "per_layer"}
@@ -34,7 +42,6 @@ def test_entries_have_only_their_keys():
         assert set(c) == {"name", "source", "file", "reduced", "why"}
     for w in SPEC["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] == 1
     for m in SPEC["end_to_end"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
                                           "source"}
@@ -45,6 +52,21 @@ def test_entries_have_only_their_keys():
                           "moves", "workloads"}
         assert m["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
+
+
+def _cells(*chips):
+    return [{"name": f"c{i}", "chips": c} for i, c in enumerate(chips)]
+
+
+@pytest.mark.parametrize("workloads,allowed", [
+    (SPEC["workloads"], True),
+    (_cells(4), True),  # one cell may always take four chips
+    (_cells(4, 1, 4, 1), True),  # half of the cells
+    (_cells(4, 4, 1), False),  # more than half, rounded down
+    (_cells(1, 2), False),  # 1 or 4 chips, nothing else
+], ids=["spec", "1-of-1", "2-of-4", "2-of-3", "2-chips"])
+def test_chips_rule(workloads, allowed):
+    assert chips_allowed(workloads) is allowed
 
 
 def test_names_units_and_text_in_their_charsets():

@@ -1,9 +1,11 @@
 """Each per-layer reader's work count against hand arithmetic, and the
 roofline share it makes of a measured time."""
+import json
 import sys
 from pathlib import Path
 from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
 BENCH = Path(__file__).resolve().parents[1]
@@ -71,6 +73,25 @@ def test_reroute_share_needs_a_batch():
     read = reader("select.reroute_share").read
     assert read(SimpleNamespace(counters={})) is None
     assert read(SimpleNamespace(counters={"ann": dict(ANN, calls=0)})) is None
+
+
+def test_batch_counts_m_from_the_configuration():
+    """The ann counters take m from the cell's configuration, not from
+    the backend's arrays: an index whose ``impl`` has no ``projected``
+    (as ``sharded-flat``'s has none) still reports it."""
+    import drivers
+
+    cfg = json.loads((BENCH / "configs" / "deep1m.json").read_text())
+    cfg["index"]["m"] = 7
+    mix = {"kind": "batch", "batch": 2, "k": 3, "pool_batches": 2}
+    drv = drivers.Batch(run.Cell("t", cfg, mix, 1, 5))
+    drv.x_host = np.zeros((16, 4), np.float32)
+    drv.pool = np.zeros((4, 4), np.float32)
+    answer = SimpleNamespace(indices=np.zeros((2, 3), np.int64),
+                             distances=np.zeros((2, 3), np.float32))
+    drv.index = SimpleNamespace(impl=SimpleNamespace(),
+                                search=lambda q, k: answer)
+    assert drv.window(0.0).counters["ann"]["m"] == 7
 
 
 def test_roofline_share_of_a_measured_time():
